@@ -1,0 +1,6 @@
+"""Host encode and pack: the program's ``encode`` stage clock (summed
+over the packing threads) over the window, per 1,000 articles."""
+
+from benchmark.readers import stage_ms_per_1k
+
+read = stage_ms_per_1k("encode")
