@@ -15,16 +15,11 @@ not telemetry is enabled):
   (``dedup_fused_tile``, ``matcher_screen_tile``, ``sharded_fused_tile``,
   the legacy parity transports) and the tile shape (``RxW`` — bounded
   cardinality: the chunkers only emit the O(log bs)-per-width prewarmed
-  set).  Timing mode is **async-submit** by default: the clock stops when
-  the dispatch call returns, i.e. it measures the submission/queueing
-  cost on the host (what an async pipeline actually pays per tile; a
-  fully-hidden kernel reads ~0, exactly like the ``kernel`` stage
-  histogram).  ``ASTPU_DISPATCH_TIMING=fenced`` blocks until the result
-  is ready before stopping the clock — ground-truth per-dispatch device
-  latency, at the cost of serialising the pipeline (a measurement mode,
-  never a production default; the always-on
-  ``astpu_dispatch_timing_fenced`` gauge says which mode produced the
-  numbers so two runs are never compared across modes unknowingly).
+  set).  The clock stops when the dispatch call returns, i.e. it
+  measures the submission/queueing cost on the host (what an async
+  pipeline actually pays per tile; a fully-hidden kernel reads ~0,
+  exactly like the ``kernel`` stage histogram).  Device time per
+  program comes from the profiler trace, not from this histogram.
 - **``astpu_dispatch_queue_lag_seconds{graph}``** — the staged-pop gap
   through ``pipeline/dispatch.py``: how long a transferred tile sat in
   the staged window before the caller's thread popped it for dispatch.
@@ -55,13 +50,12 @@ dispatch, and two C-level jit-cache-size reads per instrumented call —
 noise against millisecond-scale dispatches (regression-gated with the
 profiler's overhead test).  This module never imports jax at module
 scope (jax-free processes — shard servers, tool parents — import obs
-freely); the fenced block and the compile listener import it lazily
-inside call paths that only exist when jax is already loaded.
+freely); the compile listener imports it lazily inside a call path that
+only exists when jax is already loaded.
 """
 
 from __future__ import annotations
 
-import os
 import threading
 import time
 from contextlib import contextmanager
@@ -73,7 +67,6 @@ __all__ = [
     "QUEUE_LAG_HISTOGRAM",
     "JIT_COMPILES",
     "COMPILE_SECONDS",
-    "resolve_timing_mode",
     "dispatch_span",
     "queue_lag_histogram",
     "instrument_jit",
@@ -94,15 +87,6 @@ _compile_counters: dict[str, telemetry.Counter] = {}
 _listener_installed = False
 
 
-def resolve_timing_mode() -> str:
-    """``"async"`` (default: the clock stops at dispatch-call return) or
-    ``"fenced"`` (``ASTPU_DISPATCH_TIMING=fenced``: block-until-ready
-    truth).  Read per span — an env lookup per tile, so sweeps can flip
-    the mode between runs without re-importing anything."""
-    v = os.environ.get("ASTPU_DISPATCH_TIMING", "").strip().lower()
-    return "fenced" if v == "fenced" else "async"
-
-
 def _latency_hist(kernel: str, shape: str, shard: str | None):
     key = (kernel, shape, shard)
     h = _hists.get(key)
@@ -113,37 +97,13 @@ def _latency_hist(kernel: str, shape: str, shard: str | None):
         h = telemetry.REGISTRY.histogram(
             "astpu_dispatch_latency_seconds",
             "per-dispatch wall clock by kernel/tile-shape (async-submit "
-            "timing unless ASTPU_DISPATCH_TIMING=fenced)",
+            "timing: the host's cost of the call)",
             always=True,
             **labels,
         )
         with _lock:
             _hists[key] = h
     return h
-
-
-def _mark_timing_mode(mode: str) -> None:
-    """Stamp which timing discipline produced the latency numbers (0 =
-    async-submit, 1 = fenced) — set on EVERY observation, not just when
-    a new series appears, so a mid-run ``ASTPU_DISPATCH_TIMING`` flip on
-    a steady shape set is still visible on ``/metrics``.  Cost: one
-    gauge set per tile."""
-    telemetry.REGISTRY.gauge(
-        "astpu_dispatch_timing_fenced",
-        "1 = dispatch latency is block-until-ready truth, 0 = "
-        "async submission cost",
-        always=True,
-    ).set(1.0 if mode == "fenced" else 0.0)
-
-
-class _Span:
-    """Mutable result carrier for :func:`dispatch_span` — set ``out`` to
-    the dispatch's return value so fenced mode knows what to wait on."""
-
-    __slots__ = ("out",)
-
-    def __init__(self):
-        self.out = None
 
 
 @contextmanager
@@ -159,42 +119,21 @@ def dispatch_span(
 
     ::
 
-        with devprof.dispatch_span("dedup_fused_tile", rows=r, width=w) as sp:
+        with devprof.dispatch_span("dedup_fused_tile", rows=r, width=w):
             out = step(running, dev, ...)
-            sp.out = out
 
     Only successful dispatches are observed (an OOM-backoff retry must
-    not pollute the distribution with its failed parent attempt).  Under
-    ``ASTPU_DISPATCH_TIMING=fenced`` the exit blocks on ``sp.out`` before
-    stopping the clock.  ``trace`` attaches a slow-call exemplar when the
-    observation lands in the histogram's top bucket.
+    not pollute the distribution with its failed parent attempt).
+    ``trace`` attaches a slow-call exemplar when the observation lands in
+    the histogram's top bucket.
     """
-    span = _Span()
     shape = f"{rows}x{width}" if rows is not None and width is not None else ""
     t0 = time.perf_counter()
-    ok = False
-    try:
-        yield span
-        ok = True
-    finally:
-        if ok:
-            mode = resolve_timing_mode()
-            if mode == "fenced" and span.out is not None:
-                # a DEVICE error surfacing at the fence propagates (the
-                # dispatch failed, just asynchronously — observing it
-                # would pollute the distribution with the OOM ladder's
-                # failed parent attempts); only a non-jax/non-array
-                # result (tests) falls back to async timing
-                try:
-                    import jax
-
-                    jax.block_until_ready(span.out)
-                except (ImportError, TypeError, AttributeError):
-                    pass
-            _mark_timing_mode(mode)
-            _latency_hist(
-                kernel, shape, None if shard is None else str(shard)
-            ).observe(time.perf_counter() - t0, trace=trace)
+    yield
+    # reached only when the body returned: a raising dispatch skips this
+    _latency_hist(kernel, shape, None if shard is None else str(shard)).observe(
+        time.perf_counter() - t0, trace=trace
+    )
 
 
 def queue_lag_histogram(graph: str) -> telemetry.Histogram:

@@ -32,7 +32,7 @@ import threading
 import time
 from contextlib import contextmanager
 
-from advanced_scrapper_tpu.obs import telemetry
+from advanced_scrapper_tpu.obs import telemetry, trace
 
 _lock = threading.Lock()
 _hists: dict[str, telemetry.Histogram] = {}
@@ -44,9 +44,24 @@ STAGES = (
     "h2d",
     "kernel",
     "resolve",
+    # the precision tier's sub-stages: they tile the rerank hook
+    "rerank_readback",
+    "rerank_worklist",
+    "rerank_sketch",
+    "rerank_settle",
+    "rerank_exact",
+    "rerank_cluster",
+    "rerank_evict",
+    # the one-shot resolve after the hook returns
+    "resolve_wait",
+    "decisions",
     "matcher_build",
+    "matcher_rows",
     "matcher_screen",
+    "matcher_screen_wait",  # nested in matcher_screen
     "matcher_verify",
+    "matcher_verify_busy",  # summed over the verify workers
+    "matcher_verify_queue",  # summed over the verify slices
 )
 
 
@@ -71,6 +86,32 @@ def timed(stage: str):
         yield
     finally:
         add(stage, time.perf_counter() - t0)
+
+
+class Tally:
+    """The stage clocks of one unit of work (a corpus, a chunk), each
+    added to its histogram once when the unit ends (:meth:`flush`), never
+    per item inside it.  :meth:`timed` runs its body inside the trace
+    span of the same scope (``obs/trace.py``), so the clock and the
+    profiler's ``astpu.<span>`` interval cover the same work."""
+
+    def __init__(self):
+        self.seconds: dict[str, float] = {}
+
+    @contextmanager
+    def timed(self, stage: str, span: str):
+        with trace.span(span):
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                dt = time.perf_counter() - t0
+                self.seconds[stage] = self.seconds.get(stage, 0.0) + dt
+
+    def flush(self) -> None:
+        for stage, seconds in self.seconds.items():
+            add(stage, seconds)
+        self.seconds.clear()
 
 
 def reset() -> None:

@@ -14,6 +14,15 @@ Recording is OFF unless ``ASTPU_TELEMETRY`` is truthy or
 forked children inherit, mirroring ``ASTPU_CHAOS_FS``).  Disabled,
 :func:`span` costs one attribute check before yielding.
 
+Every :func:`span` is also a profiler trace annotation named
+``astpu.<span name>`` wherever jax is already loaded in the process,
+recording or not: a profiler trace (``jax.profiler.start_trace``) then
+holds the program's own spans on the host planes, on the clock the
+device events are placed on, so an idle gap on the device can be put
+down to the span that was open on the host.  This module never imports
+jax (jax-free processes — shard servers, tool parents — stay jax-free);
+with the profiler off an annotation costs one object per span.
+
 The dump path deliberately bypasses the ``storage.fsio`` seam: the
 recorder fires *during* injected storage faults, and routing its own
 sidecar through the faulty substrate would recurse the injection (and
@@ -28,6 +37,7 @@ import contextvars
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -35,6 +45,7 @@ from contextlib import contextmanager
 
 __all__ = [
     "FlightRecorder",
+    "PROFILER_PREFIX",
     "RECORDER",
     "enabled",
     "set_enabled",
@@ -146,6 +157,18 @@ def context_from_wire(frag) -> tuple[str, str] | None:
     return (t, s if isinstance(s, str) and s else new_span_id())
 
 
+#: the prefix of every span's name on the profiler trace
+PROFILER_PREFIX = "astpu."
+
+
+def _annotation(name: str):
+    """The profiler trace annotation for span ``name``, or None where jax
+    is not loaded (looked up, never imported)."""
+    prof = sys.modules.get("jax.profiler")
+    cls = getattr(prof, "TraceAnnotation", None)
+    return None if cls is None else cls(PROFILER_PREFIX + name)
+
+
 class FlightRecorder:
     """Bounded ring of structured events; thread-safe; cheap when off."""
 
@@ -197,7 +220,8 @@ class FlightRecorder:
     @contextmanager
     def span(self, name: str, **fields):
         """Timed span; on any exit (including exception) the duration and
-        outcome land in the ring.
+        outcome land in the ring.  The body also runs inside the
+        ``astpu.<name>`` profiler annotation where jax is loaded.
 
         Spans participate in the trace context: an explicit ``trace=``
         field starts/continues that trace; otherwise the ambient context's
@@ -206,6 +230,16 @@ class FlightRecorder:
         nested spans — and RPC calls, which serialize the context into
         their request headers — chain into one stitched corpus trace.
         """
+        ann = _annotation(name)
+        if ann is None:
+            with self._recorded(name, fields):
+                yield
+        else:
+            with ann, self._recorded(name, fields):
+                yield
+
+    @contextmanager
+    def _recorded(self, name: str, fields: dict):
         if not self.active:
             yield
             return

@@ -255,13 +255,17 @@ def _label_components(rep_bands, ok, valid, jump_rounds: int):
     idx = jnp.arange(B, dtype=jnp.int32)
     cand = jnp.where(ok, rep_bands, idx[:, None])  # self-edges are no-ops
     lab = idx
+    # the scopes name each phase's ops on the profiler trace
     for _ in range(jump_rounds):
-        pulled = jnp.take(lab, cand, axis=0).min(axis=1)
-        lab = jnp.minimum(lab, pulled)
-        lab = lab.at[cand.reshape(-1)].min(
-            jnp.broadcast_to(lab[:, None], (B, nc)).reshape(-1)
-        )
-        lab = jnp.take(lab, lab)  # pointer doubling
+        with jax.named_scope("resolve.pull"):
+            pulled = jnp.take(lab, cand, axis=0).min(axis=1)
+            lab = jnp.minimum(lab, pulled)
+        with jax.named_scope("resolve.scatter_min"):
+            lab = lab.at[cand.reshape(-1)].min(
+                jnp.broadcast_to(lab[:, None], (B, nc)).reshape(-1)
+            )
+        with jax.named_scope("resolve.jump"):
+            lab = jnp.take(lab, lab)  # pointer doubling
     return jnp.where(valid, lab, idx)
 
 

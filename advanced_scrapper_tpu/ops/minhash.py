@@ -213,12 +213,14 @@ def make_fused_tile_step(params: MinHashParams, backend: str):
         width: int,
         num_articles: int,
     ) -> jnp.ndarray:
-        tok, lens, owners = unpack_tile(packed, rows, width)
-        sigs = block_fn(tok, lens, params)
-        part = jax.ops.segment_min(
-            sigs, owners, num_segments=num_articles, indices_are_sorted=False
-        )
-        return jnp.minimum(running, part)
+        with jax.named_scope("minhash.fused_tile"):  # its ops on the trace
+            tok, lens, owners = unpack_tile(packed, rows, width)
+            sigs = block_fn(tok, lens, params)
+            part = jax.ops.segment_min(
+                sigs, owners, num_segments=num_articles,
+                indices_are_sorted=False,
+            )
+            return jnp.minimum(running, part)
 
     return fused_tile_step
 

@@ -651,16 +651,13 @@ class NearDupEngine:
 
             def dispatch(running, item):
                 dev, rows, w, _nb, _pms = item
-                # latency ledger: per-dispatch wall clock by kernel/shape
-                # (async-submit timing; ASTPU_DISPATCH_TIMING=fenced
-                # blocks until ready for ground truth)
+                # latency ledger: per-dispatch submit cost by kernel/shape
                 with devprof.dispatch_span(
                     "dedup_fused_tile", rows=rows, width=w, trace=tid
-                ) as sp:
+                ):
                     out = step(
                         running, dev, rows=rows, width=w, num_articles=n_bucket
                     )
-                    sp.out = out
                 # counted on success, INSIDE the fn: the OOM-backoff
                 # ladder then ledgers exactly its leaf dispatches
                 stages.count_dispatch("dedup")
@@ -720,12 +717,11 @@ class NearDupEngine:
                 with devprof.dispatch_span(
                     "dedup_legacy_tile",
                     rows=int(t.shape[0]), width=int(t.shape[1]), trace=tid,
-                ) as sp:
+                ):
                     out = accumulate_block_signatures(
                         running, block_fn(t, l, params), o,
                         num_articles=n_bucket,
                     )
-                    sp.out = out
                 return out
 
         running = jnp.full((n_bucket, params.num_perm), U32_MAX, jnp.uint32)
@@ -1148,11 +1144,10 @@ class NearDupEngine:
             # per-shard truth lives in the put/dispatch count ledger below
             with devprof.dispatch_span(
                 "sharded_fused_tile", rows=rows, width=w, trace=tid
-            ) as sp:
+            ):
                 out = step(
                     running, packed, rows=rows, width=w, num_articles=n_bucket
                 )
-                sp.out = out
             # one partitioned launch = one execution per shard
             for s in local_rows:
                 stages.count_dispatch("sharded", shard=s)
@@ -1658,7 +1653,9 @@ class NearDupEngine:
 
         raw, sigs, keys, valid, rep_bands, n_bucket, tid = self._prepare(texts)
         self._m_docs["oneshot"].inc(n)
-        with trace.span("dedup.resolve", trace=tid, regime="oneshot", docs=n):
+        with stages.timed("resolve_wait"), trace.span(
+            "dedup.resolve", trace=tid, regime="oneshot", docs=n
+        ):
             if self._rerank_applied:
                 # the tier settled every cell by TRUE (sketch/exact)
                 # Jaccard and already paid its precision eviction —
@@ -1679,8 +1676,11 @@ class NearDupEngine:
             )
             stages.count_dispatch("dedup")
             out = np.asarray(rep)[:n]
-        self._count_result("oneshot", n, out)
-        self._emit_decisions("oneshot", out, keys, n)
+        with stages.timed("decisions"), trace.span(
+            "dedup.decisions", trace=tid, docs=n
+        ):
+            self._count_result("oneshot", n, out)
+            self._emit_decisions("oneshot", out, keys, n)
         return out
 
     def keep(self, texts: Sequence[str | bytes]) -> np.ndarray:

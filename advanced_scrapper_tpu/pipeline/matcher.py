@@ -833,10 +833,13 @@ def _packed_screen(
         stages.count_device_put(buf.nbytes, "matcher")
         return dev, nrows, w, buf.nbytes, time.perf_counter() - t0
 
+    waits = stages.Tally()  # added once per chunk
+
     def scatter(result) -> None:
         mask_dev, own_dev = result
-        m = np.asarray(mask_dev)  # readback sync: waits for THIS tile only
-        own = np.asarray(own_dev)
+        with waits.timed("matcher_screen_wait", "matcher.screen_wait"):
+            m = np.asarray(mask_dev)  # readback sync: THIS tile only
+            own = np.asarray(own_dev)
         keep = (m & 1).astype(bool)
         for local in range(m.shape[0]):
             a = int(own[local])
@@ -875,10 +878,9 @@ def _packed_screen(
             t0 = time.perf_counter()
             with stages.timed("matcher_screen"), devprof.dispatch_span(
                 "matcher_screen_tile", rows=nrows, width=w
-            ) as sp:
+            ):
                 # async dispatch; trailing tiles drain below
                 out = step(dev, threshold, rows=nrows, width=w)
-                sp.out = out
             stages.count_dispatch("matcher")
             results.append(out)
             if probe is not None:
@@ -902,6 +904,7 @@ def _packed_screen(
     with stages.timed("matcher_screen"):
         for result in results:
             scatter(result)
+    waits.flush()
     return masks, prunes
 
 
@@ -976,11 +979,10 @@ def _legacy_screen(
         with stages.timed("matcher_screen"), devprof.dispatch_span(
             "matcher_screen_legacy",
             rows=int(tok.shape[0]), width=int(tok.shape[1]),
-        ) as sp:
+        ):
             got = match_screen(
                 tok_d, tl_d, ttl_d, ln_d, tables, threshold=threshold
             )
-            sp.out = got
             stages.count_dispatch("matcher")
         for i in range(len(batch)):
             # articles longer than the screen block fall back to full scan
@@ -1094,7 +1096,7 @@ def match_chunk_async(
         ctrl = getattr(index, "refine_controller", None)
         use_refine = ctrl.verdict() if ctrl is not None else False
 
-    from advanced_scrapper_tpu.obs import telemetry, trace
+    from advanced_scrapper_tpu.obs import stages, telemetry, trace
 
     m_articles = telemetry.counter(
         "astpu_matcher_articles_total", "articles entering the matcher"
@@ -1105,68 +1107,61 @@ def match_chunk_async(
     tid = trace.new_trace_id()
 
     rows = []
-    # plain dicts, not Series: ~100 µs/row cheaper to build, identical
-    # mapping access in _get_col, and far cheaper to pickle to pool workers
-    for row in chunk.to_dict("records"):
-        text = _get_col(row, "article_text", "article")
-        title = _get_col(row, "title")
-        raw_date = _get_col(row, "date_time", "datetime", default="")
-        try:
-            adate = dateparser.parse(raw_date) if raw_date else None
-        except (ValueError, OverflowError, dateparser.ParserError):
-            adate = None
-        rows.append((text, title, adate, row))
+    with stages.timed("matcher_rows"), trace.span("matcher.rows", trace=tid):
+        # plain dicts, not Series: ~100 µs/row cheaper to build, identical
+        # mapping access in _get_col, and far cheaper to pickle to workers
+        for row in chunk.to_dict("records"):
+            text = _get_col(row, "article_text", "article")
+            title = _get_col(row, "title")
+            raw_date = _get_col(row, "date_time", "datetime", default="")
+            try:
+                adate = dateparser.parse(raw_date) if raw_date else None
+            except (ValueError, OverflowError, dateparser.ParserError):
+                adate = None
+            rows.append((text, title, adate, row))
 
     m_articles.inc(len(rows))
     masks: list[np.ndarray | None] = [None] * len(rows)
     text_prunes: list[set | None] = [None] * len(rows)
     if use_screen and index.entries:
-        # knob resolution: explicit args win, else the ASTPU_MATCH_* env
-        # (run_matcher passes its MatchConfig fields through explicitly)
-        if None in (
-            packed, screen_tile_bytes, dispatch_window, screen_put_workers
-        ):
-            _cfg = _match_cfg()
-            packed = _cfg.packed if packed is None else packed
-            if screen_tile_bytes is None:
-                screen_tile_bytes = _cfg.screen_tile_bytes
-            if dispatch_window is None:
-                dispatch_window = _cfg.dispatch_window
-            if screen_put_workers is None:
-                screen_put_workers = _cfg.put_workers
-        screen_put_workers = screen_put_workers or 1
-        t_screen = time.perf_counter()
-        if packed:
-            # the PR 9 plane: byte-budget width-bucketed tiles, ONE packed
-            # put + ONE fused screen(+bound) dispatch per tile, pipelined
-            # (retired screen_batch is ignored here — MIGRATION.md)
-            masks, text_prunes = _packed_screen(
-                rows,
-                index,
-                use_refine=bool(use_refine),
-                threshold=threshold,
-                screen_block=screen_block,
-                tile_bytes=screen_tile_bytes,
-                window=dispatch_window,
-                put_workers=screen_put_workers,
-            )
-        else:
-            masks, text_prunes = _legacy_screen(
-                rows,
-                index,
-                use_refine=bool(use_refine),
-                threshold=threshold,
-                screen_batch=screen_batch,
-                screen_block=screen_block,
-            )
-        if trace.RECORDER.active:
-            trace.record(
-                "span",
-                "matcher.screen",
-                trace=tid,
-                articles=len(rows),
-                dur_ms=round((time.perf_counter() - t_screen) * 1e3, 3),
-            )
+        with trace.span("matcher.screen", trace=tid, articles=len(rows)):
+            # knob resolution: explicit args win, else the ASTPU_MATCH_* env
+            # (run_matcher passes its MatchConfig fields through explicitly)
+            if None in (
+                packed, screen_tile_bytes, dispatch_window, screen_put_workers
+            ):
+                _cfg = _match_cfg()
+                packed = _cfg.packed if packed is None else packed
+                if screen_tile_bytes is None:
+                    screen_tile_bytes = _cfg.screen_tile_bytes
+                if dispatch_window is None:
+                    dispatch_window = _cfg.dispatch_window
+                if screen_put_workers is None:
+                    screen_put_workers = _cfg.put_workers
+            screen_put_workers = screen_put_workers or 1
+            if packed:
+                # the packed plane: byte-budget width-bucketed tiles, ONE packed
+                # put + ONE fused screen(+bound) dispatch per tile, pipelined
+                # (retired screen_batch is ignored here — MIGRATION.md)
+                masks, text_prunes = _packed_screen(
+                    rows,
+                    index,
+                    use_refine=bool(use_refine),
+                    threshold=threshold,
+                    screen_block=screen_block,
+                    tile_bytes=screen_tile_bytes,
+                    window=dispatch_window,
+                    put_workers=screen_put_workers,
+                )
+            else:
+                masks, text_prunes = _legacy_screen(
+                    rows,
+                    index,
+                    use_refine=bool(use_refine),
+                    threshold=threshold,
+                    screen_batch=screen_batch,
+                    screen_block=screen_block,
+                )
 
     if pool is not None and len(rows) > 1:
         # ship (text, title, date, row-INDEX) out; the full row record stays
@@ -1178,22 +1173,27 @@ def match_chunk_async(
             pool.submit(
                 _verify_slice,
                 light[lo:hi], masks[lo:hi], text_prunes[lo:hi], threshold,
+                time.monotonic(),
             )
             for lo, hi in zip(bounds[:-1], bounds[1:])
             if hi > lo
         ]
 
         def collect():
-            from advanced_scrapper_tpu.obs import stages
-
             out = []
+            busy = queued = 0.0
             with stages.timed("matcher_verify"), trace.span(
                 "matcher.verify", trace=tid, articles=len(rows)
             ):
                 for f in futures:  # slice order == row order
-                    out.extend(
-                        (ticker, m, rows[i][3]) for ticker, m, i in f.result()
-                    )
+                    got, busy_s, queued_s = f.result()
+                    busy += busy_s
+                    queued += queued_s
+                    out.extend((ticker, m, rows[i][3]) for ticker, m, i in got)
+            # the workers' own clocks: they never reach this process's
+            # histograms, so their sums are added here, once per chunk
+            stages.add("matcher_verify_busy", busy)
+            stages.add("matcher_verify_queue", queued)
             m_matches.inc(len(out))
             return out
 
@@ -1201,12 +1201,10 @@ def match_chunk_async(
         return collect
 
     def collect():
-        from advanced_scrapper_tpu.obs import stages
-
         out = []
-        with stages.timed("matcher_verify"), trace.span(
-            "matcher.verify", trace=tid, articles=len(rows)
-        ):
+        with stages.timed("matcher_verify"), stages.timed(
+            "matcher_verify_busy"
+        ), trace.span("matcher.verify", trace=tid, articles=len(rows)):
             for (text, title, adate, row), mask, pruned in zip(
                 rows, masks, text_prunes
             ):
@@ -1279,17 +1277,23 @@ def _warm_noop() -> bool:
     return True
 
 
-def _verify_slice(rows, masks, prunes, threshold: float):
+def _verify_slice(rows, masks, prunes, threshold: float, submitted: float):
     """Run the host exact-verify rules over one row slice (no jax, no
     device: masks/prunes were computed by the screen in the parent).
-    ``rows`` carry row INDICES, echoed back for parent-side re-attach."""
+    ``rows`` carry row INDICES, echoed back for parent-side re-attach.
+
+    Returns ``(matches, busy_s, queued_s)``: the worker's seconds in the
+    rules, and the seconds from the parent's submit (``submitted``, a
+    ``time.monotonic()`` stamp: the clock is system-wide on Linux, so the
+    two processes' stamps compare) to this worker's start."""
+    start = time.monotonic()
     index = _WORKER_INDEX
     out = []
     for (text, title, adate, row_ix), mask, pruned in zip(rows, masks, prunes):
         matches = match_article(text, title, adate, index, mask, threshold, pruned)
         for ticker, m in matches.items():
             out.append((ticker, m, row_ix))
-    return out
+    return out, time.monotonic() - start, start - submitted
 
 
 def make_verify_pool(index: EntityIndex, workers: int | None = None):

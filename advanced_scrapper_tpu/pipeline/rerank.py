@@ -259,18 +259,31 @@ class RerankTier:
         return bool(attr[0] == attr[1])
 
     def __call__(self, raw: Sequence[bytes], sigs, rep_bands, valid):
+        from advanced_scrapper_tpu.obs import stages
+
+        # seven stage clocks that tile the call, each a trace span of the
+        # same scope, added once per corpus
+        clk = stages.Tally()
+        try:
+            return self._rerank(raw, sigs, rep_bands, valid, clk)
+        finally:
+            clk.flush()
+
+    def _rerank(self, raw, sigs, rep_bands, valid, clk):
         from advanced_scrapper_tpu.cpu.oracle import jaccard, shingle_set
         from advanced_scrapper_tpu.utils.bloom import pack_keys64
 
         cfg = self.cfg
         thr = cfg.sim_threshold
         n = len(raw)
-        sigs_np = np.asarray(sigs)
-        rb = np.asarray(rep_bands)
-        valid_np = np.asarray(valid)
+        with clk.timed("rerank_readback", "dedup.rerank.readback"):
+            sigs_np = np.asarray(sigs)
+            rb = np.asarray(rep_bands)
+            valid_np = np.asarray(valid)
         n_bucket, nc = rb.shape
 
-        pair_arr, stats = self._candidate_pairs(sigs_np, rb, valid_np, n)
+        with clk.timed("rerank_worklist", "dedup.rerank.worklist"):
+            pair_arr, stats = self._candidate_pairs(sigs_np, rb, valid_np, n)
         m = pair_arr.shape[0]
         self.stats = stats
         stats.update(
@@ -288,18 +301,21 @@ class RerankTier:
         self.last_evicted: set[int] = set()
         self.last_participants: set[int] = set()
         if m == 0:
-            out, _ = oprr.rewrite_rep_bands(n_bucket, nc, [])
+            with clk.timed("rerank_evict", "dedup.rerank.evict"):
+                out, _ = oprr.rewrite_rep_bands(n_bucket, nc, [])
             return out
 
-        participating = np.zeros(n, bool)
-        participating[np.unique(pair_arr)] = True
-        self.last_participants = set(np.unique(pair_arr).tolist())
-        sketches = oprr.bottom_sketches(
-            raw, self.params.shingle_k, cfg.rerank_sketch,
-            skip=~(participating & valid_np[:n]),
-        )
+        with clk.timed("rerank_sketch", "dedup.rerank.sketch"):
+            participating = np.zeros(n, bool)
+            participating[np.unique(pair_arr)] = True
+            self.last_participants = set(np.unique(pair_arr).tolist())
+            sketches = oprr.bottom_sketches(
+                raw, self.params.shingle_k, cfg.rerank_sketch,
+                skip=~(participating & valid_np[:n]),
+            )
 
-        jq, verdict, n_tiles, h2d = self._settle_device(pair_arr, sketches)
+        with clk.timed("rerank_settle", "dedup.rerank.settle"):
+            jq, verdict, n_tiles, h2d = self._settle_device(pair_arr, sketches)
         stats["tiles"] = n_tiles
         stats["h2d_bytes"] = h2d
 
@@ -319,12 +335,6 @@ class RerankTier:
         border = np.flatnonzero(verdict == -1)
         stats["borderline"] = int(border.size)
         keys64 = None
-        if self.index is not None and border.size:
-            keys64 = pack_keys64(
-                oprr.band_keys_wide_host(
-                    sigs_np[:n], np.asarray(self.params.band_salt)
-                )
-            )
 
         def settle_exact(i: int, j: int, jq_ij: int) -> bool:
             nonlocal exact_used
@@ -341,92 +351,104 @@ class RerankTier:
             prov[key] = "rerank"  # cap overflow: the sketch verdict stands
             return jq_ij >= thr_q
 
-        for s in border:
-            keep[s] = settle_exact(
-                int(pair_arr[s, 0]), int(pair_arr[s, 1]), int(jq[s])
-            )
+        with clk.timed("rerank_exact", "dedup.rerank.exact"):
+            if self.index is not None and border.size:
+                keys64 = pack_keys64(
+                    oprr.band_keys_wide_host(
+                        sigs_np[:n], np.asarray(self.params.band_salt)
+                    )
+                )
+            for s in border:
+                keep[s] = settle_exact(
+                    int(pair_arr[s, 0]), int(pair_arr[s, 1]), int(jq[s])
+                )
         stats["exact_checks"] = exact_used
 
         # cluster the settled keep-edges, then classify EVERY
         # within-cluster pair (wave-2: residual pairs the candidacy never
         # proposed are settled on host — sketch twin, margin → exact)
-        reps = oprr.union_find(n, pair_arr[keep])
-        clusters: dict[int, list[int]] = {}
-        for i in np.flatnonzero(valid_np[:n]):
-            clusters.setdefault(int(reps[i]), []).append(int(i))
-        clusters = {r: ms for r, ms in clusters.items() if len(ms) > 1}
-        stats["clusters"] = len(clusters)
+        with clk.timed("rerank_cluster", "dedup.rerank.cluster"):
+            reps = oprr.union_find(n, pair_arr[keep])
+            clusters: dict[int, list[int]] = {}
+            for i in np.flatnonzero(valid_np[:n]):
+                clusters.setdefault(int(reps[i]), []).append(int(i))
+            clusters = {r: ms for r, ms in clusters.items() if len(ms) > 1}
+            stats["clusters"] = len(clusters)
 
-        settled = {
-            (int(a), int(b)): (bool(k), int(q))
-            for (a, b), k, q in zip(pair_arr, keep, jq)
-        }
-        margin = cfg.rerank_margin
-        lanes = sigs_np.shape[1]
-        # expected oracle-recall mass of the WHOLE candidate work-list —
-        # candidacy is a superset of the estimator oracle's (coarse
-        # buckets ⊆ candidates), so this prices the full recall
-        # denominator, killed pairs included.  The eviction floor is
-        # (live caught mass / this total): a number that maps directly
-        # onto the measured-recall bar instead of an in-cluster ratio.
-        total_op_mass = sum(
-            oprr.op_weight(int(q) / oprr.SCALE, lanes, thr) for q in jq
-        )
-        pairinfo: dict[tuple[int, int], tuple[bool, float]] = {}
-        for r, ms in clusters.items():
-            for x in range(len(ms)):
-                for y in range(x + 1, len(ms)):
-                    a, b = ms[x], ms[y]
-                    key = (a, b)
-                    if key in settled:
-                        is_keep, q = settled[key]
-                        w = oprr.op_weight(q / oprr.SCALE, lanes, thr)
-                    else:
-                        jhat = oprr.sketch_jaccard(
-                            sketches[a], sketches[b]
-                        )
+            settled = {
+                (int(a), int(b)): (bool(k), int(q))
+                for (a, b), k, q in zip(pair_arr, keep, jq)
+            }
+            margin = cfg.rerank_margin
+            lanes = sigs_np.shape[1]
+            # expected oracle-recall mass of the WHOLE candidate work-list
+            # — candidacy is a superset of the estimator oracle's (coarse
+            # buckets ⊆ candidates), so this prices the full recall
+            # denominator, killed pairs included.  The eviction floor is
+            # (live caught mass / this total): a number that maps directly
+            # onto the measured-recall bar instead of an in-cluster ratio.
+            total_op_mass = sum(
+                oprr.op_weight(int(q) / oprr.SCALE, lanes, thr) for q in jq
+            )
+            pairinfo: dict[tuple[int, int], tuple[bool, float] | None] = {}
+            # margin pairs settle below, in this order, under the exact
+            # clock; their slots are held here so the order stays the loop's
+            wave2_margin: list[tuple[int, int, int]] = []
+            for r, ms in clusters.items():
+                for x in range(len(ms)):
+                    for y in range(x + 1, len(ms)):
+                        a, b = ms[x], ms[y]
+                        key = (a, b)
+                        if key in settled:
+                            is_keep, q = settled[key]
+                            w = oprr.op_weight(q / oprr.SCALE, lanes, thr)
+                            pairinfo[key] = (not is_keep, w)
+                            continue
+                        jhat = oprr.sketch_jaccard(sketches[a], sketches[b])
                         if abs(jhat - thr) < margin:
-                            is_keep = settle_exact(
-                                a, b, oprr.quantize(jhat)
-                            )
+                            pairinfo[key] = None
+                            wave2_margin.append((a, b, oprr.quantize(jhat)))
                         else:
-                            is_keep = jhat >= thr
-                        # transitive extras the candidacy never proposed
-                        # sit outside the estimator oracle's coarse
-                        # buckets: merged or not, the recall denominator
-                        # never counts them, so they carry zero mass —
-                        # pure precision entries the eviction can drop
-                        # for free
-                        w = 0.0
-                    pairinfo[key] = (not is_keep, w)
+                            # transitive extras the candidacy never
+                            # proposed sit outside the estimator oracle's
+                            # coarse buckets: merged or not, the recall
+                            # denominator never counts them, so they carry
+                            # zero mass — pure precision entries the
+                            # eviction can drop for free
+                            pairinfo[key] = (not jhat >= thr, 0.0)
+        with clk.timed("rerank_exact", "dedup.rerank.exact"):
+            for a, b, q in wave2_margin:
+                pairinfo[(a, b)] = (not settle_exact(a, b, q), 0.0)
+            shingles.clear()  # freed under the clock of the work that built them
         stats["exact_checks"] = exact_used
 
-        evicted, pprec = oprr.evict_for_precision(
-            clusters,
-            pairinfo,
-            cfg.rerank_precision_target,
-            recall_floor=cfg.rerank_recall_floor,
-            total_op_mass=total_op_mass,
-        )
-        stats["evicted"] = len(evicted)
-        stats["predicted_precision"] = pprec
-        self.last_evicted = {int(d) for d in evicted}
+        with clk.timed("rerank_evict", "dedup.rerank.evict"):
+            evicted, pprec = oprr.evict_for_precision(
+                clusters,
+                pairinfo,
+                cfg.rerank_precision_target,
+                recall_floor=cfg.rerank_recall_floor,
+                total_op_mass=total_op_mass,
+            )
+            stats["evicted"] = len(evicted)
+            stats["predicted_precision"] = pprec
+            self.last_evicted = {int(d) for d in evicted}
 
-        # surviving settled-TRUE cluster edges become the new candidate
-        # matrix.  Truth, not the estimator: the engine's own lane
-        # agreement is just another draw around the true J, and gating
-        # edges on it re-drops exactly the proven-true pairs whose
-        # signatures underestimate — the pairs the settle tier exists to
-        # save.  Both resolve paths trust the authoritative rewrite
-        # (``_rerank_applied``), so no downstream screen re-litigates.
-        edges = []
-        for r, ms in clusters.items():
-            live = [d for d in ms if d not in evicted]
-            for x in range(len(live)):
-                for y in range(x + 1, len(live)):
-                    a, b = live[x], live[y]
-                    if not pairinfo[(a, b)][0]:
-                        edges.append((a, b))
-        out, dropped = oprr.rewrite_rep_bands(n_bucket, nc, edges)
+            # surviving settled-TRUE cluster edges become the new candidate
+            # matrix.  Truth, not the estimator: the engine's own lane
+            # agreement is just another draw around the true J, and gating
+            # edges on it re-drops exactly the proven-true pairs whose
+            # signatures underestimate — the pairs the settle tier exists
+            # to save.  Both resolve paths trust the authoritative rewrite
+            # (``_rerank_applied``), so no downstream screen re-litigates.
+            edges = []
+            for r, ms in clusters.items():
+                live = [d for d in ms if d not in evicted]
+                for x in range(len(live)):
+                    for y in range(x + 1, len(live)):
+                        a, b = live[x], live[y]
+                        if not pairinfo[(a, b)][0]:
+                            edges.append((a, b))
+            out, dropped = oprr.rewrite_rep_bands(n_bucket, nc, edges)
         stats["dropped_cells"] = dropped
         return out

@@ -177,25 +177,6 @@ def test_dispatch_latency_ledger_and_queue_lag():
     assert lag and lag[0].count > 0
 
 
-def test_dispatch_timing_mode_resolution(monkeypatch):
-    monkeypatch.delenv("ASTPU_DISPATCH_TIMING", raising=False)
-    assert devprof.resolve_timing_mode() == "async"
-    monkeypatch.setenv("ASTPU_DISPATCH_TIMING", "fenced")
-    assert devprof.resolve_timing_mode() == "fenced"
-    monkeypatch.setenv("ASTPU_DISPATCH_TIMING", "banana")
-    assert devprof.resolve_timing_mode() == "async"
-
-
-def test_fenced_timing_mode_marks_gauge_and_observes(monkeypatch):
-    monkeypatch.setenv("ASTPU_DISPATCH_TIMING", "fenced")
-    eng = _engine()
-    np.asarray(eng.dedup_reps_async(_uniform_corpus(1, n=96)))
-    marks = telemetry.REGISTRY.find("astpu_dispatch_timing_fenced")
-    assert marks and marks[0].value == 1.0
-    lat = telemetry.REGISTRY.find(devprof.DISPATCH_HISTOGRAM)
-    assert sum(h.count for h in lat) > 0
-
-
 def test_dispatch_span_skips_failed_dispatches():
     with pytest.raises(RuntimeError):
         with devprof.dispatch_span("boom_kernel", rows=64, width=64):
@@ -572,19 +553,6 @@ def test_queue_lag_excludes_put_time():
         f"lag sum {lag[0].sum:.3f}s ≈ put time — the stamp is on the "
         "wrong side of the transfer"
     )
-
-
-def test_timing_mode_flip_visible_midrun(monkeypatch):
-    """astpu_dispatch_timing_fenced tracks EVERY observation, so an env
-    flip on a steady shape set (cached histogram handles) still lands."""
-    monkeypatch.delenv("ASTPU_DISPATCH_TIMING", raising=False)
-    with devprof.dispatch_span("flip_kernel", rows=1, width=1):
-        pass
-    assert telemetry.REGISTRY.find("astpu_dispatch_timing_fenced")[0].value == 0.0
-    monkeypatch.setenv("ASTPU_DISPATCH_TIMING", "fenced")
-    with devprof.dispatch_span("flip_kernel", rows=1, width=1):
-        pass  # same (kernel, shape): the histogram handle is cached
-    assert telemetry.REGISTRY.find("astpu_dispatch_timing_fenced")[0].value == 1.0
 
 
 def test_sampler_survives_registry_reset():

@@ -936,7 +936,6 @@ def test_lint_metrics_covers_perf_obs_series():
     for name, owner in (
         ("astpu_dispatch_latency_seconds", "devprof.py"),
         ("astpu_dispatch_queue_lag_seconds", "devprof.py"),
-        ("astpu_dispatch_timing_fenced", "devprof.py"),
         ("astpu_jit_compiles_total", "devprof.py"),
         ("astpu_jit_compile_seconds", "devprof.py"),
         ("astpu_prof_samples_total", "profiler.py"),
