@@ -14,6 +14,10 @@ pipeline, runtime, index or obs imports (enforced by
   (≈0.014 at S=1024, 3× tighter than the 128-perm signature) and is
   EXACT whenever ``|union| ≤ S`` — i.e. for every document pair short
   enough that both shingle sets fit the sketch.
+- **exact shingle ids** (:func:`shingle_ids`, :func:`exact_jaccard`):
+  the host re-settle of margin pairs — one sorted array of exact
+  shingle ids per document, Jaccard counted on integers, equal to
+  ``cpu.oracle.jaccard`` of the shingle sets to the last bit.
 - the **vmap'd settle kernel** (:func:`make_rerank_tile_step`): one
   packed pair tile (``ops.pack.pack_pair_tile``) in, per-pair
   quantized Jaccard scattered into a device-resident fold buffer out —
@@ -55,11 +59,13 @@ __all__ = [
     "bottom_sketches",
     "coarse_pairs",
     "evict_for_precision",
+    "exact_jaccard",
     "make_rerank_finalize",
     "make_rerank_tile_step",
     "pair_tile_nbytes",
     "quantize",
     "rewrite_rep_bands",
+    "shingle_ids",
     "signature_agreement",
     "sketch_jaccard",
     "union_find",
@@ -89,6 +95,61 @@ def _mix64(x: np.ndarray) -> np.ndarray:
     return x ^ (x >> np.uint64(31))
 
 
+# -- exact shingle ids ------------------------------------------------------
+
+
+def _utf8(text: str | bytes) -> bytes:
+    return (
+        text.encode("utf-8", errors="replace")
+        if isinstance(text, str)
+        else bytes(text)
+    )
+
+
+def shingle_ids(text: str | bytes, k: int) -> np.ndarray:
+    """Sorted unique ids of the k-byte shingle set — ``cpu.oracle.
+    shingle_set`` as one array (utf-8 ``errors="replace"``, ``len < k``
+    → empty), exact with no hashing.
+
+    For ``k ≤ 8`` a window's id is its bytes packed little-endian into a
+    ``uint64`` (byte ``j`` at bits ``8j``): one unaligned 8-byte load
+    per offset over the zero-padded text, masked to ``k`` bytes.  Longer
+    shingles are compared as ``k``-byte ``np.void`` records."""
+    raw = _utf8(text)
+    if k <= 8:
+        if len(raw) < k:
+            return np.zeros(0, np.uint64)
+        buf = np.frombuffer(raw + bytes(8 - k), np.uint8)
+        win = np.ndarray(
+            (len(raw) - k + 1,), np.dtype("<u8"), buffer=buf, strides=(1,)
+        )
+        return np.unique(win & np.uint64((1 << (8 * k)) - 1))
+    rec = np.dtype((np.void, k))
+    if len(raw) < k:
+        return np.zeros(0, rec)
+    win = np.lib.stride_tricks.sliding_window_view(
+        np.frombuffer(raw, np.uint8), k
+    )
+    return np.unique(np.ascontiguousarray(win).view(rec).ravel())
+
+
+def exact_jaccard(ids_a: np.ndarray, ids_b: np.ndarray) -> float:
+    """True Jaccard of two :func:`shingle_ids` arrays, equal to
+    ``cpu.oracle.jaccard`` of the sets: the intersection is counted as
+    an integer and divided by the integer union size; both empty → 1.0,
+    one empty → 0.0."""
+    na, nb = ids_a.size, ids_b.size
+    if na == 0 and nb == 0:
+        return 1.0
+    if na == 0 or nb == 0:
+        return 0.0
+    if na > nb:
+        ids_a, ids_b, na, nb = ids_b, ids_a, nb, na
+    at = np.minimum(np.searchsorted(ids_b, ids_a), nb - 1)
+    inter = int(np.count_nonzero(ids_b[at] == ids_a))
+    return inter / (na + nb - inter)
+
+
 # -- bottom-S sketches ------------------------------------------------------
 
 
@@ -98,27 +159,27 @@ def bottom_sketch(text: str | bytes, k: int, size: int) -> np.ndarray:
     Shingle semantics mirror ``cpu.oracle.shingle_set`` exactly (utf-8
     ``errors="replace"``, ``len < k`` → empty set → all-PAD sketch), so
     the sketch estimator converges on the oracle's TRUE Jaccard.  Ids
-    are exact for ``k ≤ 8`` (bytes packed into uint64); longer shingles
-    fold the tail bytes FNV-style.  All numpy, no per-shingle Python.
+    are exact for ``k ≤ 8`` (:func:`shingle_ids`); longer shingles fold
+    the tail bytes FNV-style.  All numpy, no per-shingle Python.
     """
-    raw = (
-        text.encode("utf-8", errors="replace")
-        if isinstance(text, str)
-        else bytes(text)
-    )
     out = np.full((size,), PAD, np.uint32)
-    if len(raw) < k:
-        return out
-    b = np.frombuffer(raw, np.uint8)
-    win = np.lib.stride_tricks.sliding_window_view(b, k)
-    ids = np.zeros(win.shape[0], np.uint64)
-    for j in range(min(k, 8)):
-        ids |= win[:, j].astype(np.uint64) << np.uint64(8 * j)
-    for j in range(8, k):
-        ids = ((ids * np.uint64(0x100000001B3)) & _M64) ^ win[:, j].astype(
-            np.uint64
-        )
-    h = (_mix64(np.unique(ids)) >> np.uint64(32)).astype(np.uint32)
+    if k <= 8:
+        ids = shingle_ids(text, k)
+    else:
+        raw = _utf8(text)
+        if len(raw) < k:
+            return out
+        b = np.frombuffer(raw, np.uint8)
+        win = np.lib.stride_tricks.sliding_window_view(b, k)
+        ids = np.zeros(win.shape[0], np.uint64)
+        for j in range(8):
+            ids |= win[:, j].astype(np.uint64) << np.uint64(8 * j)
+        for j in range(8, k):
+            ids = ((ids * np.uint64(0x100000001B3)) & _M64) ^ win[:, j].astype(
+                np.uint64
+            )
+        ids = np.unique(ids)
+    h = (_mix64(ids) >> np.uint64(32)).astype(np.uint32)
     h = np.unique(h)
     h = h[h != PAD]
     m = min(size, h.size)

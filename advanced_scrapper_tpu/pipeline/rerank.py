@@ -270,7 +270,6 @@ class RerankTier:
             clk.flush()
 
     def _rerank(self, raw, sigs, rep_bands, valid, clk):
-        from advanced_scrapper_tpu.cpu.oracle import jaccard, shingle_set
         from advanced_scrapper_tpu.utils.bloom import pack_keys64
 
         cfg = self.cfg
@@ -320,13 +319,16 @@ class RerankTier:
         stats["h2d_bytes"] = h2d
 
         # host re-settle of the margin band: exact Jaccard up to the cap,
-        # then the ANN re-probe, else the sketch verdict stands
-        shingles: dict[int, set] = {}
+        # then the ANN re-probe, else the sketch verdict stands; each
+        # document's sorted shingle ids are built once, on first use
+        shingles: dict[int, np.ndarray] = {}
 
-        def sset(i: int) -> set:
+        def ids(i: int) -> np.ndarray:
             s = shingles.get(i)
             if s is None:
-                s = shingles[i] = shingle_set(raw[i], self.params.shingle_k)
+                s = shingles[i] = oprr.shingle_ids(
+                    raw[i], self.params.shingle_k
+                )
             return s
 
         exact_used = 0
@@ -342,7 +344,7 @@ class RerankTier:
             if exact_used < cfg.rerank_exact_cap:
                 exact_used += 1
                 prov[key] = "margin"
-                return jaccard(sset(i), sset(j)) >= thr
+                return oprr.exact_jaccard(ids(i), ids(j)) >= thr
             rp = self._reprobe(i, j, keys64)
             if rp is not None:
                 stats["reprobes"] += 1
