@@ -24,7 +24,17 @@ from typing import Iterable, Sequence
 
 import numpy as np
 
-from advanced_scrapper_tpu.core.hashing import MAX_HASH, MERSENNE_PRIME, MinHashParams
+from advanced_scrapper_tpu.core.hashing import (
+    MAX_HASH,
+    MERSENNE_PRIME,
+    MinHashParams,
+    fmix32_np,
+)
+
+#: FNV-1a 32-bit constants of the band-key fold (``ops/shingle.py``)
+_FNV_OFFSET = np.uint32(0x811C9DC5)
+_FNV_PRIME = np.uint32(0x01000193)
+_U32_MAX = np.uint32(0xFFFFFFFF)
 
 
 def sha1_hash32(data: bytes) -> int:
@@ -193,6 +203,113 @@ def oracle_dedup_reps(
                 lo, hi = min(ri, rj), max(ri, rj)
                 parent[hi] = lo
     return np.array([find(i) for i in range(n)], dtype=np.int32)
+
+
+def _salted_band_keys(sigs: np.ndarray, salts: np.ndarray) -> np.ndarray:
+    """``uint32[N, len(salts)]``: each band's ``P / len(salts)`` rows
+    folded FNV-1a style from the offset basis, XOR the band's salt, then
+    murmur3's fmix32."""
+    n, p = sigs.shape
+    nb = len(salts)
+    rows = sigs.reshape(n, nb, p // nb)
+    k = np.full((n, nb), _FNV_OFFSET, np.uint32)
+    for j in range(p // nb):
+        k = (k ^ rows[:, :, j]) * _FNV_PRIME
+    return fmix32_np(k ^ salts.astype(np.uint32)[None, :])
+
+
+def _subband_salts(num: int, seed: int = 0x5B5C9A02) -> np.ndarray:
+    """The fine sub-band salts: ``(i · 0x9E3779B97F4A7C15 + seed) mod
+    2**32`` for ``i < num``."""
+    i = np.arange(num, dtype=np.uint64)
+    return ((i * np.uint64(0x9E3779B97F4A7C15) + np.uint64(seed))
+            & np.uint64(0xFFFFFFFF)).astype(np.uint32)
+
+
+def _run_candidates(col: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """``(row, candidate)`` pairs of one key column, self pairs dropped:
+    rows sorted by (key, index); in each run of equal keys every row
+    proposes the run head (its smallest index), its predecessor and its
+    predecessor's predecessor in that order."""
+    idx = np.arange(len(col))
+    si = np.lexsort((idx, col))
+    sk = col[si]
+    start = np.r_[True, sk[1:] != sk[:-1]]
+    run = np.cumsum(start) - 1
+    head = si[start][run]
+    pred = np.where(start, si, np.r_[si[:1], si[:-1]])
+    same2 = np.r_[False, False, run[2:] == run[:-2]]
+    pred2 = np.where(same2, np.r_[si[:2], si[:-2]], si)
+    rows, cands = [], []
+    for cand in (head, pred, pred2):
+        m = cand != si
+        rows.append(si[m])
+        cands.append(cand[m])
+    return np.concatenate(rows), np.concatenate(cands)
+
+
+def oracle_lsh_reps(
+    sigs: np.ndarray,
+    params: MinHashParams,
+    *,
+    cand_subbands: int,
+    threshold: float,
+    valid: np.ndarray,
+) -> np.ndarray:
+    """``int32[N]``: the estimator-only LSH resolution the device runs
+    (``ops/lsh.py`` ``duplicate_rep_bands`` + ``resolve_rep_bands``, as
+    ``dedup_reps_async`` and ``dedup_reps_sharded`` use it with
+    ``fine_margin`` 0), restated in plain numpy from its semantics.
+
+    - Keys: ``params.num_bands`` coarse bands and ``cand_subbands`` fine
+      sub-bands of ``num_perm / cand_subbands`` rows, each the salted
+      FNV-1a fold finalised by fmix32 (coarse salts
+      ``params.band_salt``, fine salts the sub-band salts).  Invalid rows
+      (``valid`` false: fewer than k bytes) take key ``U32_MAX`` and sort
+      in with everything else.
+    - Candidates: per key column, sorted by (key, index), each row's run
+      head, run predecessor and predecessor².
+    - Edges: a (row, candidate) pair with both ends valid whose count of
+      equal lanes over ``num_perm``, in float32, is at least
+      ``float32(threshold)``.
+    - Representative: the smallest index of the row's connected
+      component under the edges (union-find).
+
+    Departures from the device's wording: the device labels components
+    by a fixed number of pull / scatter-min / pointer-doubling rounds
+    (``_label_components``); this runs union-find to the end, so a
+    device resolve that stops short of the fixpoint differs.  Bucket
+    padding rows past ``N`` are not modelled: their keys are
+    ``U32_MAX`` and their indices exceed every real row's, so they
+    change no real row's run head or predecessors.  The per-edge fine
+    bars of ``fine_margin > 0`` are not modelled."""
+    sigs = np.asarray(sigs, np.uint32)
+    n, p = sigs.shape
+    valid = np.asarray(valid, bool)
+    if valid.shape != (n,):
+        raise ValueError(f"valid has shape {valid.shape}, want ({n},)")
+    keys = _salted_band_keys(sigs, np.asarray(params.band_salt))
+    if cand_subbands:
+        keys = np.concatenate(
+            [keys, _salted_band_keys(sigs, _subband_salts(cand_subbands))], axis=1
+        )
+    keys[~valid] = _U32_MAX
+    pairs = [_run_candidates(keys[:, c]) for c in range(keys.shape[1])]
+    a = np.concatenate([r for r, _ in pairs])
+    b = np.concatenate([c for _, c in pairs])
+    both = valid[a] & valid[b]
+    lo, hi = np.minimum(a[both], b[both]), np.maximum(a[both], b[both])
+    edge = np.unique(lo.astype(np.int64) * n + hi)
+    lo, hi = edge // n, edge % n
+    agree = np.empty(len(edge), np.float32)
+    for s in range(0, len(edge), 65536):
+        eq = sigs[lo[s : s + 65536]] == sigs[hi[s : s + 65536]]
+        agree[s : s + 65536] = eq.sum(axis=1).astype(np.float32) / np.float32(p)
+    keep = agree >= np.float32(threshold)
+    edges = set(zip(lo[keep].tolist(), hi[keep].tolist()))
+    # union-find by the smallest index (``oracle_reps``; it reads only
+    # the length of its texts when the pairs are given)
+    return oracle_reps(range(n), params, threshold, pairs=edges).astype(np.int32)
 
 
 def oracle_near_dup_pairs(

@@ -55,6 +55,10 @@ STAGES = (
     # the one-shot resolve after the hook returns
     "resolve_wait",
     "decisions",
+    # the mesh-sharded plane: its whole tile loop, and the cross-chip
+    # epilogue dispatch with its readback
+    "sharded_tiles",
+    "sharded_epilogue",
     "matcher_build",
     "matcher_rows",
     "matcher_screen",
@@ -173,6 +177,10 @@ def _dev(name: str, regime: str, shard: str | None = None) -> telemetry.Counter:
                 "astpu_device_puts_total": "explicit jax.device_put calls",
                 "astpu_device_dispatches_total": "jitted device dispatches",
                 "astpu_h2d_bytes_total": "host→device bytes shipped by puts",
+                "astpu_sharded_tile_slots_total":
+                    "tile slots of the sharded plane's partitioned dispatches",
+                "astpu_sharded_pad_tiles_total":
+                    "zero tiles padded into a leftover sharded tile group",
             }[name],
             **labels,
         )
@@ -240,6 +248,26 @@ def regime_device_counters(regime: str) -> dict[str, float]:
     return out
 
 
+def count_sharded_tile_slot(shard: int | str, *, padded: bool) -> None:
+    """Record one tile slot of a partitioned sharded dispatch on
+    ``shard``; ``padded``: the slot holds a zero tile that pads a
+    leftover group (``NearDupEngine._sharded_tile_groups``)."""
+    shard = str(shard)
+    _dev("astpu_sharded_tile_slots_total", "sharded", shard).inc()
+    if padded:
+        _dev("astpu_sharded_pad_tiles_total", "sharded", shard).inc()
+
+
+def sharded_tile_counters() -> dict[str, dict[str, float]]:
+    """Per-shard cumulative ``{shard: {"tile_slots", "pad_tiles"}}``:
+    tile slots dispatched and, of them, zero tiles padded in.  Subtract
+    two snapshots to window a corpus or a benchmark window."""
+    return _per_shard({
+        "astpu_sharded_tile_slots_total": "tile_slots",
+        "astpu_sharded_pad_tiles_total": "pad_tiles",
+    }, "sharded")
+
+
 def sharded_device_counters(regime: str = "sharded") -> dict[str, dict[str, float]]:
     """Per-shard cumulative device-traffic totals for one regime:
     ``{shard: {"device_puts", "device_dispatches", "h2d_bytes"}}`` —
@@ -247,21 +275,23 @@ def sharded_device_counters(regime: str = "sharded") -> dict[str, dict[str, floa
     carry the label).  Subtract two snapshots to window a corpus; the
     sharded launch-count gates (tier-1 and the MULTICHIP dryrun) assert
     every shard's delta is exactly tiles + 1 / tiles + 1."""
-    short = {
+    return _per_shard({
         "astpu_device_puts_total": "device_puts",
         "astpu_device_dispatches_total": "device_dispatches",
         "astpu_h2d_bytes_total": "h2d_bytes",
-    }
+    }, regime)
+
+
+def _per_shard(short: dict[str, str], regime: str) -> dict[str, dict[str, float]]:
+    """``{shard: {key: total}}`` of the shard-labelled counters named in
+    ``short`` (counter name → key) for one regime."""
     out: dict[str, dict[str, float]] = {}
     for name, key in short.items():
         for c in telemetry.REGISTRY.find(name):
             shard = c.labels.get("shard")
             if shard is None or c.labels.get("regime") != regime:
                 continue
-            per = out.setdefault(
-                shard,
-                {"device_puts": 0.0, "device_dispatches": 0.0, "h2d_bytes": 0.0},
-            )
+            per = out.setdefault(shard, dict.fromkeys(short.values(), 0.0))
             per[key] += c.value
     return out
 

@@ -197,13 +197,15 @@ def make_sharded_fused_tile_step(mesh: Mesh, params: MinHashParams, backend: str
     ) -> jnp.ndarray:
         def local(run_l, packed_l):
             # run_l: uint32[1, num_articles, P]; packed_l: uint8[1, nbytes]
-            tok, lens, owners = unpack_tile(packed_l[0], rows, width)
-            sigs = block_fn(tok, lens, params)
-            part = jax.ops.segment_min(
-                sigs, owners, num_segments=num_articles,
-                indices_are_sorted=False,
-            )
-            return jnp.minimum(run_l, part[None])
+            # (the scope names the step's ops on the profiler trace)
+            with jax.named_scope("sharded.tile"):
+                tok, lens, owners = unpack_tile(packed_l[0], rows, width)
+                sigs = block_fn(tok, lens, params)
+                part = jax.ops.segment_min(
+                    sigs, owners, num_segments=num_articles,
+                    indices_are_sorted=False,
+                )
+                return jnp.minimum(run_l, part[None])
 
         return jax.shard_map(
             local,
@@ -248,7 +250,9 @@ def make_sharded_resolve_epilogue(
     @partial(jax.jit, static_argnames=("jump_rounds",))
     def sharded_resolve(running, valid, *, jump_rounds: int):
         def local(run_l, valid_l):
-            sig = jax.lax.pmin(run_l[0], axes)
+            # the scope names the cross-chip combine on the profiler trace
+            with jax.named_scope("sharded.pmin"):
+                sig = jax.lax.pmin(run_l[0], axes)
             if use_oph:
                 from advanced_scrapper_tpu.ops.oph import densify
 
@@ -295,7 +299,8 @@ def make_sharded_keys_epilogue(mesh: Mesh, params: MinHashParams, backend: str):
     @jax.jit
     def sharded_keys(running):
         def local(run_l):
-            sig = jax.lax.pmin(run_l[0], axes)
+            with jax.named_scope("sharded.pmin"):
+                sig = jax.lax.pmin(run_l[0], axes)
             if use_oph:
                 from advanced_scrapper_tpu.ops.oph import densify
 

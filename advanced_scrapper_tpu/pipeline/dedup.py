@@ -1035,7 +1035,7 @@ class NearDupEngine:
             self._sharded_steps[key] = epi
         return epi
 
-    def _sharded_tile_groups(self, tiles, nsh: int):
+    def _sharded_tile_groups(self, tiles, nsh: int, shards=None):
         """Group the shared chunker's same-shape tiles into per-shard
         groups of ``nsh`` — one group = one partitioned dispatch, each
         shard owning one tile.  The min-combine is order- and
@@ -1044,15 +1044,28 @@ class NearDupEngine:
         tiles (lens 0 ⇒ all-``U32_MAX`` signatures, the min identity —
         exactly how in-tile padding rows already behave), so every
         shard's ledger stays uniform: tiles + 1 puts, tiles + 1
-        dispatches per corpus, per shard."""
+        dispatches per corpus, per shard.  Each group handed on counts
+        one tile slot on each of ``shards`` (default: all), and a slot
+        that holds a padding tile counts as one
+        (``stages.sharded_tile_counters``)."""
+        from advanced_scrapper_tpu.obs import stages
+
+        shards = range(nsh) if shards is None else shards
+
+        def counted(group, real: int):
+            for s in shards:
+                stages.count_sharded_tile_slot(s, padded=s >= real)
+            return group
+
         pending: dict = {}
         for t, l, o in tiles:
             shape = (t.shape[0], t.shape[1])
             bucket = pending.setdefault(shape, [])
             bucket.append((t, l, o))
             if len(bucket) == nsh:
-                yield shape, pending.pop(shape)
+                yield counted((shape, pending.pop(shape)), nsh)
         for (rows, w), bucket in list(pending.items()):
+            real = len(bucket)
             while len(bucket) < nsh:
                 bucket.append(
                     (
@@ -1061,7 +1074,7 @@ class NearDupEngine:
                         np.zeros((rows,), np.int32),
                     )
                 )
-            yield (rows, w), bucket
+            yield counted(((rows, w), bucket), real)
 
     def _accumulate_device_sharded(self, raw: list, mesh, trace_id=None):
         """``(running, n_bucket, use_oph)`` — the sharded twin of
@@ -1105,7 +1118,6 @@ class NearDupEngine:
         devices = shard_row_devices(mesh)
         local_rows = local_shard_rows(mesh)
         step = self._get_sharded_fused_step(mesh)
-        tiles = self._sharded_tile_groups(self._host_tiles(raw, trace_id=tid), nsh)
 
         from advanced_scrapper_tpu.ops.pack import packed_nbytes
 
@@ -1155,38 +1167,45 @@ class NearDupEngine:
 
         running = self._get_sharded_init(mesh)(num_articles=n_bucket)
         probe = self.dispatch_probe
-        pipe = PipelinedDispatcher(
-            tiles,
-            pack=pack,
-            put=put,
-            put_workers=resolve_put_workers(cfg),
-            window=cfg.dispatch_window,
-            name="dedup.sharded.h2d",
-        )
         dispatched = 0
-        try:
-            for item in pipe:
-                t0 = time.perf_counter()
-                rows = int(item[1])
-                with stages.timed("kernel"), self.step_timer.step(rows * nsh):
-                    running = dispatch(running, item)
-                if probe is not None:
-                    probe(
-                        {
-                            "tile": dispatched,
-                            "rows": rows,
-                            "width": int(item[2]),
-                            "shards": nsh,
-                            "h2d_bytes": int(item[3]),
-                            "put_ms": round(item[4] * 1e3, 3),
-                            "dispatch_ms": round(
-                                (time.perf_counter() - t0) * 1e3, 3
-                            ),
-                        }
-                    )
-                dispatched += 1
-        finally:
-            pipe.close()
+        # the whole tile loop, the chunker's eager prologue included
+        with stages.timed("sharded_tiles"), trace.span(
+            "dedup.sharded.tiles", trace=tid, docs=n, shards=nsh
+        ):
+            tiles = self._sharded_tile_groups(
+                self._host_tiles(raw, trace_id=tid), nsh, local_rows
+            )
+            pipe = PipelinedDispatcher(
+                tiles,
+                pack=pack,
+                put=put,
+                put_workers=resolve_put_workers(cfg),
+                window=cfg.dispatch_window,
+                name="dedup.sharded.h2d",
+            )
+            try:
+                for item in pipe:
+                    t0 = time.perf_counter()
+                    rows = int(item[1])
+                    with stages.timed("kernel"), self.step_timer.step(rows * nsh):
+                        running = dispatch(running, item)
+                    if probe is not None:
+                        probe(
+                            {
+                                "tile": dispatched,
+                                "rows": rows,
+                                "width": int(item[2]),
+                                "shards": nsh,
+                                "h2d_bytes": int(item[3]),
+                                "put_ms": round(item[4] * 1e3, 3),
+                                "dispatch_ms": round(
+                                    (time.perf_counter() - t0) * 1e3, 3
+                                ),
+                            }
+                        )
+                    dispatched += 1
+            finally:
+                pipe.close()
         self._m_batches.inc(dispatched)
         self.last_tiles = dispatched
         if trace.RECORDER.active:
@@ -1313,6 +1332,8 @@ class NearDupEngine:
         epi = self._get_sharded_epilogue(mesh)
         with stages.timed("resolve"), trace.span(
             "dedup.resolve", trace=tid, regime="sharded", docs=n
+        ), stages.timed("sharded_epilogue"), trace.span(
+            "dedup.sharded.epilogue", trace=tid, docs=n
         ):
             rep = epi(running, valid, jump_rounds=_jump_rounds(n_bucket))
             for s in local_shard_rows(mesh):
