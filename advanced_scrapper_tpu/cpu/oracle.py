@@ -276,10 +276,11 @@ def oracle_lsh_reps(
       component under the edges (union-find).
 
     Departures from the device's wording: the device labels components
-    by a fixed number of pull / scatter-min / pointer-doubling rounds
-    (``_label_components``); this runs union-find to the end, so a
-    device resolve that stops short of the fixpoint differs.  Bucket
-    padding rows past ``N`` are not modelled: their keys are
+    by rounds of pull / scatter-min / pointer doubling
+    (``_label_components``) that stop at its fixpoint or at its
+    ``jump_rounds`` cap, whichever comes first; this runs union-find to
+    the end, so a device resolve that the cap cuts short of the fixpoint
+    differs.  Bucket padding rows past ``N`` are not modelled: their keys are
     ``U32_MAX`` and their indices exceed every real row's, so they
     change no real row's run head or predecessors.  The per-edge fine
     bars of ``fine_margin > 0`` are not modelled."""

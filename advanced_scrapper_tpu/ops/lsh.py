@@ -6,9 +6,10 @@ The TPU formulation turns "same bucket" into a *sort*: for every band,
 rows are sorted by (band key, row index); equal-key runs are bucket
 collisions, and the run head (smallest row index — i.e. first seen) becomes
 every member's candidate representative.  A signature-agreement check
-verifies candidates, and log₂(B) rounds of pointer jumping resolve chains so
-the final representative array has union-find semantics — all without
-leaving the device or introducing data-dependent shapes.
+verifies candidates, and at most log₂(B) rounds of pointer jumping
+(fewer where the labels settle sooner) resolve chains so the final
+representative array has union-find semantics — all without leaving the
+device or introducing data-dependent shapes.
 
 Sorting is the idiomatic XLA substitute for hash tables: ``lax.sort`` is a
 fused multi-operand bitonic sort that tiles well on TPU, whereas scattered
@@ -213,7 +214,9 @@ def resolve_rep_bands(
     pairwise agreement ≥ threshold yet landed in different clusters).
     Label propagation: pull the min label along edges, push it back with a
     scatter-min, then pointer-double — symmetric, monotone, and fixpoint =
-    component min within ``jump_rounds`` ≥ ceil(log2(B)) rounds.
+    component min within ``jump_rounds`` ≥ ceil(log2(B)) rounds.  The
+    loop stops after the first round that changes no label (the fixpoint)
+    or after ``jump_rounds`` rounds, whichever comes first.
     A merge still requires signature agreement — candidates that fail
     verification contribute no edge.  ``threshold`` may be a scalar, a
     per-candidate-COLUMN ``float32[nc]`` vector, or a per-EDGE
@@ -245,28 +248,50 @@ def resolve_rep_bands(
         & valid[:, None]
         & jnp.take(valid, rep_bands)
     )
-    return _label_components(rep_bands, ok, valid, jump_rounds)
+    return _label_components(rep_bands, ok, valid, jump_rounds)[0]
 
 
 def _label_components(rep_bands, ok, valid, jump_rounds: int):
     """Connected-component min labels over the ``ok`` edge set (the shared
-    back half of :func:`resolve_rep_bands` / :func:`resolve_rep_bands_from_ok`)."""
+    back half of :func:`resolve_rep_bands` / :func:`resolve_rep_bands_from_ok`):
+    ``(labels int32[B], rounds int32[])``, the rounds the loop ran (the
+    entry points return the labels alone).
+
+    A ``lax.while_loop`` of propagation rounds that stops after the first
+    round leaving every label unchanged, or after ``jump_rounds`` rounds.
+    Each round is a deterministic function of the labels, so a round that
+    changes nothing is the fixpoint and every later round would be a
+    no-op: the labels equal those of exactly ``jump_rounds`` rounds, and
+    the test stays on the device (one dispatch, no host round trip).
+    Star clusters and short candidate runs settle in one round and
+    confirm in the second, far below the cap.
+    """
     B, nc = rep_bands.shape
     idx = jnp.arange(B, dtype=jnp.int32)
     cand = jnp.where(ok, rep_bands, idx[:, None])  # self-edges are no-ops
-    lab = idx
-    # the scopes name each phase's ops on the profiler trace
-    for _ in range(jump_rounds):
+
+    def cond(state):
+        _lab, rounds, changed = state
+        return changed & (rounds < jump_rounds)
+
+    def body(state):
+        lab0, rounds, _changed = state
+        # the scopes name each phase's ops on the profiler trace
         with jax.named_scope("resolve.pull"):
-            pulled = jnp.take(lab, cand, axis=0).min(axis=1)
-            lab = jnp.minimum(lab, pulled)
+            pulled = jnp.take(lab0, cand, axis=0).min(axis=1)
+            lab = jnp.minimum(lab0, pulled)
         with jax.named_scope("resolve.scatter_min"):
             lab = lab.at[cand.reshape(-1)].min(
                 jnp.broadcast_to(lab[:, None], (B, nc)).reshape(-1)
             )
         with jax.named_scope("resolve.jump"):
             lab = jnp.take(lab, lab)  # pointer doubling
-    return jnp.where(valid, lab, idx)
+        return lab, rounds + 1, jnp.any(lab != lab0)
+
+    lab, rounds, _changed = jax.lax.while_loop(
+        cond, body, (idx, jnp.int32(0), jnp.bool_(True))
+    )
+    return jnp.where(valid, lab, idx), rounds
 
 
 @partial(jax.jit, static_argnames=("jump_rounds",))
@@ -284,7 +309,7 @@ def resolve_rep_bands_from_ok(
     stage kills refuted edges) — re-running the chunked signature gathers
     would double the heaviest device op on the one-shot path.
     """
-    return _label_components(rep_bands, ok, valid, jump_rounds)
+    return _label_components(rep_bands, ok, valid, jump_rounds)[0]
 
 
 def subband_salt(num: int, seed: int = 0x5B5C9A02) -> _np.ndarray:

@@ -461,3 +461,153 @@ def test_resolve_rep_bands_fuzzed_vs_union_find_oracle():
         )
         want = oracle_cc(rep_bands, sigs, valid, thr)
         assert (got == want).all()
+
+
+# -- label propagation: stops at its fixpoint, bit-equal to the fixed loop ----
+
+
+def _fixed_round_labels(rep_bands, ok, valid, jump_rounds):
+    """The resolve's label propagation as it was before it learned to stop:
+    exactly ``jump_rounds`` unrolled rounds of pull, scatter-min and
+    pointer jump — the reference the early-stopping loop must equal."""
+    import jax.numpy as jnp
+
+    B, nc = rep_bands.shape
+    idx = jnp.arange(B, dtype=jnp.int32)
+    cand = jnp.where(ok, rep_bands, idx[:, None])
+    lab = idx
+    for _ in range(jump_rounds):
+        pulled = jnp.take(lab, cand, axis=0).min(axis=1)
+        lab = jnp.minimum(lab, pulled)
+        lab = lab.at[cand.reshape(-1)].min(
+            jnp.broadcast_to(lab[:, None], (B, nc)).reshape(-1)
+        )
+        lab = jnp.take(lab, lab)
+    return jnp.where(valid, lab, idx)
+
+
+def _edgeless(rng, B, nc):
+    rep_bands = rng.randint(0, B, (B, nc)).astype(np.int32)
+    return rep_bands, np.zeros((B, nc), bool)
+
+
+def _stars(rng, B, nc):
+    """Clusters whose head is their smallest member, every other member
+    proposing the head (the run-head column of ``duplicate_rep_bands``)."""
+    labels = np.sort(rng.randint(0, max(2, B // 5), B))
+    heads = np.searchsorted(labels, labels)  # first row of each cluster
+    rep_bands = np.tile(np.arange(B, dtype=np.int32)[:, None], (1, nc))
+    rep_bands[:, 0] = heads
+    ok = np.zeros((B, nc), bool)
+    ok[:, 0] = heads != np.arange(B)
+    return rep_bands, ok
+
+
+def _exact_copy_runs(rng, B, nc):
+    """Runs of exact copies scattered through the batch, linked as
+    ``duplicate_rep_bands`` links a run: head, predecessor and
+    predecessor² columns, every link verified."""
+    members = rng.randint(0, max(2, B // 4), B)
+    rep_bands = np.tile(np.arange(B, dtype=np.int32)[:, None], (1, nc))
+    for m in np.unique(members):
+        run = np.flatnonzero(members == m)
+        for k, i in enumerate(run):
+            links = (run[0], run[max(k - 1, 0)], run[max(k - 2, 0)])
+            rep_bands[i] = [links[c % 3] for c in range(nc)]
+    return rep_bands, rep_bands != np.arange(B)[:, None]
+
+
+def _long_path(rng, B, nc):
+    """One chain ``i → i - 1`` through the whole batch: the component
+    needs several rounds, so a small cap binds before the fixpoint."""
+    rep_bands = np.tile(np.arange(B, dtype=np.int32)[:, None], (1, nc))
+    rep_bands[1:, int(rng.randint(nc))] = np.arange(B - 1)
+    return rep_bands, rep_bands != np.arange(B)[:, None]
+
+
+def _label_components_jit():
+    """The resolve's label propagation with the rounds it ran: the
+    entry points return the labels alone."""
+    import jax
+
+    from advanced_scrapper_tpu.ops.lsh import _label_components
+
+    return jax.jit(_label_components, static_argnames=("jump_rounds",))
+
+
+def _fuzzed(rng, B, nc):
+    rep_bands = np.stack([rng.randint(0, B, nc) for _ in range(B)]).astype(np.int32)
+    return rep_bands, rng.rand(B, nc) < rng.uniform(0.0, 0.3)
+
+
+@pytest.mark.parametrize(
+    "graph", [_edgeless, _stars, _exact_copy_runs, _long_path, _fuzzed],
+    ids=["edgeless", "stars", "exact_copy_runs", "long_path", "fuzzed"],
+)
+def test_label_components_stops_at_fixpoint_bit_equal_to_fixed_rounds(graph):
+    """The early-stopping label propagation equals the fixed-count loop
+    bit for bit at every cap — where the fixpoint arrives first and where
+    the cap binds first — and never reports more rounds than its cap."""
+    import jax
+    import jax.numpy as jnp
+
+    from advanced_scrapper_tpu.ops.lsh import resolve_rep_bands_from_ok
+
+    fixed = jax.jit(_fixed_round_labels, static_argnames=("jump_rounds",))
+    labels = _label_components_jit()
+    rng = np.random.RandomState(2026)
+    B, nc = 64, 6
+    for _ in range(6):
+        rep_bands, ok = graph(rng, B, nc)
+        valid = rng.rand(B) > 0.1
+        args = (jnp.asarray(rep_bands), jnp.asarray(ok), jnp.asarray(valid))
+        for cap in (1, 2, 3, 6):
+            rep, rounds = labels(*args, jump_rounds=cap)
+            want = np.asarray(fixed(*args, jump_rounds=cap))
+            assert np.array_equal(np.asarray(rep), want), (graph.__name__, cap)
+            # the entry point returns the same labels
+            entry = resolve_rep_bands_from_ok(*args, jump_rounds=cap)
+            assert np.array_equal(np.asarray(entry), want), (graph.__name__, cap)
+            assert np.asarray(rounds).dtype == np.int32
+            assert 1 <= int(rounds) <= cap
+
+
+def test_label_components_reports_rounds_run():
+    """One round on an edgeless matrix (it changes nothing), two on star
+    clusters (the head's label arrives in one, the second confirms it),
+    and the whole cap on a long path the cap cuts short."""
+    import jax.numpy as jnp
+
+    labels = _label_components_jit()
+    rng = np.random.RandomState(7)
+    B, nc = 64, 6
+    valid = jnp.ones((B,), bool)
+    for graph, cap, want in ((_edgeless, 6, 1), (_stars, 6, 2), (_long_path, 2, 2)):
+        rep_bands, ok = graph(rng, B, nc)
+        rep, rounds = labels(
+            jnp.asarray(rep_bands), jnp.asarray(ok), valid, jump_rounds=cap
+        )
+        assert int(rounds) == want, graph.__name__
+    # the cut path has not reached its component minimum yet
+    assert np.asarray(rep).max() > 0
+    # uncut, it settles on label 0 and stops before a generous cap
+    rep, rounds = labels(
+        jnp.asarray(rep_bands), jnp.asarray(ok), valid, jump_rounds=16
+    )
+    assert int(rounds) < 16 and not np.asarray(rep).any()
+
+
+def test_label_components_invalid_row_keeps_its_own_index():
+    """A ``valid=False`` row reports itself even when edges touch it; the
+    valid rows around it still merge."""
+    import jax.numpy as jnp
+
+    from advanced_scrapper_tpu.ops.lsh import resolve_rep_bands_from_ok
+
+    rep_bands = jnp.asarray(np.array([[0, 0], [0, 0], [1, 1], [0, 2]], np.int32))
+    ok = rep_bands != jnp.arange(4)[:, None]
+    valid = jnp.asarray(np.array([True, True, False, True]))
+    rep = resolve_rep_bands_from_ok(rep_bands, ok, valid, jump_rounds=2)
+    assert np.asarray(rep).tolist() == [0, 0, 2, 0]
+    _, rounds = _label_components_jit()(rep_bands, ok, valid, jump_rounds=2)
+    assert 1 <= int(rounds) <= 2

@@ -98,6 +98,22 @@ def test_sharded_4x1_equals_reference(eng, mesh41, corpus, seed):
     assert np.array_equal(got, want), np.flatnonzero(got != want)[:10]
 
 
+def test_sharded_resolve_stops_early_and_stays_one_dispatch(eng, mesh41, corpus):
+    """The replicated resolve stops at its fixpoint: every representative
+    still equals the reference, and every shard still costs tiles + 1
+    puts and tiles + 1 dispatches, the resolve one of them."""
+    docs, want = corpus
+    dev0 = stages.sharded_device_counters()
+    got = eng.dedup_reps_sharded(docs, mesh41)
+    dev1 = stages.sharded_device_counters()
+    assert np.array_equal(got, want), np.flatnonzero(got != want)[:10]
+    tiles = eng.last_tiles
+    for s in range(4):
+        d = {k: v - dev0.get(str(s), {}).get(k, 0.0) for k, v in dev1[str(s)].items()}
+        assert d["device_puts"] == tiles + 1, (s, d, tiles)
+        assert d["device_dispatches"] == tiles + 1, (s, d, tiles)
+
+
 def test_sharded_1x1_equals_reference(eng, mesh11, corpus):
     docs, want = corpus
     assert np.array_equal(eng.dedup_reps_sharded(docs, mesh11), want)

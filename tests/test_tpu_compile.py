@@ -119,6 +119,25 @@ def test_candidate_epilogue_compiles(one_chip):
     assert compiled.memory_analysis() is not None
 
 
+def test_resolve_from_ok_compiles_to_one_loop(one_chip):
+    """The one-shot resolve at a 16,384 bucket and the shipped 144
+    candidate columns: the propagation rounds compile to one device
+    loop that stops at its fixpoint, in one program."""
+    from advanced_scrapper_tpu.ops.lsh import resolve_rep_bands_from_ok
+    from advanced_scrapper_tpu.pipeline.dedup import _jump_rounds
+
+    n, nc = 16384, 3 * (CFG.num_bands + CFG.cand_subbands)
+    compiled = resolve_rep_bands_from_ok.lower(
+        _sds((n, nc), jnp.int32, one_chip),
+        _sds((n, nc), jnp.bool_, one_chip),
+        _sds((n,), jnp.bool_, one_chip),
+        jump_rounds=_jump_rounds(n),
+    ).compile()
+    assert "while" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 1 << 30
+
+
 @pytest.fixture(scope="module")
 def entity_index():
     from chip_smoke import entity_universe
