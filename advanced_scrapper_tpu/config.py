@@ -116,22 +116,14 @@ class MatchConfig:
     out_dir_suffix: str = "_ticker_matched_articles"  # ref :129
     verify_workers: int = 0  # exact-verify process fan-out; 0 = cpu_count
     #                          (the ref's mp.Pool width, :231-238); 1 = inline
-    packed: bool = True      # screen tiles cross H2D as ONE packed buffer
-    #   (ops/pack.py, SCREEN_PLANES trailer) into ONE fused jitted screen
-    #   (+Myers-bound) dispatch (ops.match.make_screen_step), pipelined
-    #   through the dispatch executor — 1 put + 1 dispatch per tile.
-    #   ASTPU_MATCH_PACKED=0 restores the legacy per-batch screen loop
-    #   (multiple puts + screen-then-bound dispatches), kept byte-identical
-    #   for parity certification and as an escape hatch.
     dispatch_window: int = 0  # depth-N in-flight screen-tile window in the
     #   pipelined executor (staged-edge capacity; 0 = auto:
     #   max(2, put_workers) — same semantics as the dedup knob)
     put_workers: int = 0     # H2D put threads for screen tiles (0 = 1)
     screen_tile_bytes: int = 1 << 21  # byte budget per packed screen tile:
     #   rows-per-tile ≈ budget // row width (power-of-two bucketed, like the
-    #   dedup encoder) — replaces the retired fixed screen_batch=128 tile
-    #   sizing (MIGRATION.md), so narrow news corpora screen thousands of
-    #   rows per dispatch while 64 kB rows still tile shallowly
+    #   dedup encoder), so narrow news corpora screen thousands of rows per
+    #   dispatch while 64 kB rows still tile shallowly
     prewarm: int = 0         # compile the packed screen-step shape set at
     #   run start (pipeline.matcher.prewarm_screen): every width bucket's
     #   full tile plus its power-of-two tail chunks, screen-only AND fused
@@ -227,14 +219,12 @@ class DedupConfig:
     #   tiles ≈ window + put_workers + 1 accumulating).  0 = auto:
     #   max(2, put_workers) — double buffering on local backends, a
     #   put-worker-deep window on serializing transports.
-    packed_h2d: bool = True  # pack each tile's (tokens, lengths, owners)
-    #   into ONE contiguous buffer (ops/pack.py): per-tile H2D is one
-    #   device_put instead of three serialized round trips, and the
-    #   signature+accumulate step is ONE fused jitted dispatch with the
-    #   accumulator donated (ops.minhash.make_fused_tile_step).  False
-    #   restores the legacy 3-put/2-dispatch tile transport — kept for
-    #   parity certification (byte-identical, tested) and as an escape
-    #   hatch; both routes ride the same executor.
+    packed_h2d: bool = True  # each tile's (tokens, lengths, owners) cross
+    #   H2D as ONE contiguous buffer (ops/pack.py) into ONE fused jitted
+    #   dispatch with the accumulator donated (ops.minhash.
+    #   make_fused_tile_step).  The only transport: True is the one legal
+    #   value (kept as a field so stated configurations still read back);
+    #   False raises (MIGRATION.md).
     prewarm: int = 0         # compile the packed tile-step shape set at
     #   engine init (NearDupEngine.prewarm): every width bucket's full
     #   tile plus its O(log bs) power-of-two tail chunks.  0 = off
@@ -281,6 +271,14 @@ class DedupConfig:
     #   raise N as the corpus grows, or 0 to checkpoint only at run end,
     #   the pre-knob behaviour) — previously an inline end-of-run-only
     #   constant in pipeline/scraper.py
+
+    def __post_init__(self) -> None:
+        if self.packed_h2d is not True:
+            raise ValueError(
+                "DedupConfig.packed_h2d must be True: the unpacked tile "
+                "transport was removed (ASTPU_DEDUP_PACKED_H2D=0 no longer "
+                "selects anything; see MIGRATION.md)"
+            )
 
 
 @dataclass(frozen=True)

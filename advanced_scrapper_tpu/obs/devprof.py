@@ -12,8 +12,8 @@ not telemetry is enabled):
 
 - **``astpu_dispatch_latency_seconds{kernel, shape[, shard]}``** — one
   observation per device dispatch, labeled by the kernel seam
-  (``dedup_fused_tile``, ``matcher_screen_tile``, ``sharded_fused_tile``,
-  the legacy parity transports) and the tile shape (``RxW`` — bounded
+  (``dedup_fused_tile``, ``matcher_screen_tile``, ``sharded_fused_tile``)
+  and the tile shape (``RxW`` — bounded
   cardinality: the chunkers only emit the O(log bs)-per-width prewarmed
   set).  The clock stops when the dispatch call returns, i.e. it
   measures the submission/queueing cost on the host (what an async

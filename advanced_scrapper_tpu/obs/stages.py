@@ -150,10 +150,7 @@ def snapshot_ms() -> dict[str, float]:
 # counted: ``jax.device_put`` calls and jitted-step dispatches in the
 # instrumented pipelines — implicit transfers (numpy passed straight to
 # a jit) are exactly the shape the packed paths exist to avoid, and
-# counting them would hide that.  (One scoped exception: the LEGACY
-# matcher refine slices count their jit-arg transfers explicitly in
-# ``pipeline.matcher._refine_batch`` so the packed-vs-legacy matcher
-# ledger compares like for like.)
+# counting them would hide that.
 
 _DEV_NAMES = (
     "astpu_device_puts_total",

@@ -1,6 +1,5 @@
 from advanced_scrapper_tpu.ops.shingle import fmix32, shingle_hash
 from advanced_scrapper_tpu.ops.minhash import (
-    accumulate_block_signatures,
     combine_block_signatures,
     minhash_signatures,
 )
@@ -20,7 +19,6 @@ __all__ = [
     "shingle_hash",
     "minhash_signatures",
     "combine_block_signatures",
-    "accumulate_block_signatures",
     "band_keys",
     "candidate_keys",
     "duplicate_reps",

@@ -62,7 +62,7 @@ def prepare_names(
     nbits: int = NBITS,
     max_grams: int = MAX_GRAMS,
 ) -> dict:
-    """Host-side name tables for :func:`match_screen`.
+    """Host-side name tables for :func:`make_screen_step`.
 
     Returns arrays: ``grams int32[N, max_grams]`` (bit indices, -1 padded),
     ``kept/total int32[N]`` gram counts, ``name_len int32[N]``,
@@ -105,10 +105,13 @@ def _screen_core(
     nbits: int,
     q: int,
 ):
-    """Traceable screen body — shared by the standalone
-    :func:`match_screen` dispatch and the packed single-dispatch
+    """Traceable screen body of the packed single-dispatch
     :func:`make_screen_step` (where the name tables are closure
-    constants folded into the compiled step)."""
+    constants folded into the compiled step).  ``tokens/doc_len``
+    describe the combined ``title\\ntext`` byte rows;
+    ``text_len``/``title_len`` are the raw part lengths the soundness
+    bounds are computed from.  Returns ``bool[B, N]``: True where
+    (article, name) survives the screen."""
     h, valid = shingle_hash(tokens, doc_len, q)
     idx = jnp.where(valid, (h % jnp.uint32(nbits)).astype(jnp.int32), nbits)
     B = tokens.shape[0]
@@ -140,28 +143,6 @@ def _screen_core(
     exact_keep = (count >= kept[None, :]) & (part_max >= m)
 
     return jnp.where(fuzzy[None, :], fuzzy_keep, exact_keep)
-
-
-@partial(jax.jit, static_argnames=("nbits", "q"))
-def _screen_impl(
-    tokens,
-    text_len,
-    title_len,
-    doc_len,
-    grams,
-    kept,
-    total,
-    name_len,
-    fuzzy,
-    threshold,
-    *,
-    nbits: int,
-    q: int,
-):
-    return _screen_core(
-        tokens, text_len, title_len, doc_len, grams, kept, total, name_len,
-        fuzzy, threshold, nbits=nbits, q=q,
-    )
 
 
 #: int32 trailer planes of a packed screen tile, in order: combined
@@ -197,10 +178,7 @@ def make_screen_step(
     the Myers alignment bound into the SAME dispatch, all inside one
     jitted call.
 
-    The legacy loop pays ≥2 puts and ≥2 dispatches per batch (screen
-    arrays, then the bound kernel over host-gathered survivor pairs); each
-    is a host round trip.  Here the
-    survivor mask never leaves the device between the two stages: the
+    The survivor mask never leaves the device between the two stages: the
     bound consumes it in-kernel and overwrites the refine-candidate
     columns with the prune verdict (:data:`MASK_TEXT_PRUNED`), so a tile
     is exactly 1 put + 1 dispatch — the matcher half of the PR 9
@@ -286,38 +264,3 @@ def make_screen_step(
         return mask, owners
 
     return screen_step
-
-
-def match_screen(
-    tokens: np.ndarray,
-    text_len: np.ndarray,
-    title_len: np.ndarray,
-    doc_len: np.ndarray,
-    tables: dict,
-    *,
-    threshold: float = 95.0,
-    nbits: int = NBITS,
-    q: int = DEFAULT_Q,
-) -> np.ndarray:
-    """``bool[B, N]`` — True where (article, name) survives the screen.
-
-    ``tokens/doc_len`` describe the combined ``title\\ntext`` byte rows;
-    ``text_len``/``title_len`` are the raw part lengths the soundness bounds
-    are computed from.
-    """
-    return np.asarray(
-        _screen_impl(
-            tokens,
-            jnp.asarray(text_len, jnp.int32),
-            jnp.asarray(title_len, jnp.int32),
-            jnp.asarray(doc_len, jnp.int32),
-            jnp.asarray(tables["grams"]),
-            jnp.asarray(tables["kept"]),
-            jnp.asarray(tables["total"]),
-            jnp.asarray(tables["name_len"]),
-            jnp.asarray(tables["fuzzy"]),
-            jnp.float32(threshold),
-            nbits=nbits,
-            q=q,
-        )
-    )

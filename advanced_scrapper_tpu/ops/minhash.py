@@ -169,11 +169,10 @@ def make_fused_tile_step(params: MinHashParams, backend: str):
     article, and fold into the DONATED running accumulator, all inside
     one jitted call.
 
-    The legacy path pays two dispatches per tile (``block_fn`` then
-    :func:`accumulate_block_signatures`); each dispatch is a host round
-    trip, so halving the count is a direct latency win (SEDD's per-batch launch-minimisation argument —
-    PAPERS.md).  Donating ``running`` extends the donation already on
-    the legacy accumulate: the device updates the accumulator in place,
+    One dispatch per tile, not one for the block signatures and a
+    second for the fold: each dispatch is a host round trip (SEDD's
+    per-batch launch-minimisation argument — PAPERS.md).  ``running``
+    is donated, so the device updates the accumulator in place, with
     no per-tile ``[num_articles, P]`` allocation.
 
     ``backend == "oph"`` uses the RAW OPH form (empty bins ``U32_MAX``)
@@ -223,27 +222,3 @@ def make_fused_tile_step(params: MinHashParams, backend: str):
             return jnp.minimum(running, part)
 
     return fused_tile_step
-
-
-@partial(jax.jit, static_argnames=("num_articles",), donate_argnums=(0,))
-def accumulate_block_signatures(
-    running: jnp.ndarray,
-    block_sigs: jnp.ndarray,
-    owners: jnp.ndarray,
-    *,
-    num_articles: int,
-) -> jnp.ndarray:
-    """One streamed step of the block→article combine: fold a fixed-shape
-    batch of block signatures into the running ``uint32[num_articles, P]``
-    minimum.  Min is associative/commutative, so folding batch-by-batch is
-    bit-identical to one whole-corpus :func:`combine_block_signatures` —
-    but each step dispatches asynchronously (and donates ``running``'s
-    buffer), so host encode, H2D, and device compute overlap instead of
-    serialising on a per-batch device sync (the round-2 ragged-regime
-    bottleneck).  Padding rows carry all-``U32_MAX`` signatures (the min
-    identity): their owner index is irrelevant.
-    """
-    part = jax.ops.segment_min(
-        block_sigs, owners, num_segments=num_articles, indices_are_sorted=False
-    )
-    return jnp.minimum(running, part)

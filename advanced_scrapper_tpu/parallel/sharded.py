@@ -128,98 +128,6 @@ def make_sharded_dedup(
     return jax.jit(sharded)
 
 
-def make_sharded_block_dedup(
-    mesh: Mesh,
-    params: MinHashParams,
-    num_articles: int,
-    *,
-    threshold: float = 0.7,
-    jump_rounds: int = 16,
-    hist_bins: int = 1 << 16,
-    backend: str = "scan",
-    cand_subbands: int | None = None,
-    fine_margin: float | None = None,
-):
-    """Blockwise sharded dedup with the per-article segment-min combine
-    FUSED into the device step.
-
-    ``step(tokens, lengths, owners) -> (rep, hist)``: ``tokens`` is
-    ``uint8[B, L]`` of BLOCKS (long articles split blockwise with k-1
-    overlap, exactly like ``core.tokenizer.encode_blocks``) sharded on the
-    data axis, ``owners int32[B]`` maps each block to its global article id
-    (padding rows point at ``num_articles``, a scratch slot).  Each shard
-    folds its local blocks into a per-article partial signature with
-    ``segment_min`` and the partials combine across shards with
-    ``lax.pmin`` — MinHash's min-algebra makes the blockwise+sharded
-    combine exact, and fusing it here removes the host-side combine pass
-    (sig D2H → numpy segment-min → re-H2D for resolution) that used to sit
-    between the streaming feed and LSH resolution.  Only the compact
-    ``[num_articles, P]`` partials ride the ICI, never block signatures.
-
-    Resolution from the combined per-article signatures is identical to
-    :func:`make_sharded_dedup` (same candidate bands, same fine thresholds),
-    so streamed blockwise corpora resolve exactly like the row-per-article
-    step — parity-tested against ``NearDupEngine`` in
-    ``tests/test_encode_parity.py``.
-    """
-    data = _data_axis(mesh)
-    salt = jnp.asarray(params.band_salt)
-    k = params.shingle_k
-    _sig_fn = resolve_signature_fn(backend)
-    use_oph = backend == "oph"
-    if use_oph:
-        # raw OPH form through the combine; densify AFTER (ops/oph.py on
-        # why that order is load-bearing for blockwise exactness)
-        from advanced_scrapper_tpu.ops.oph import densify, oph_raw_signatures
-
-        _sig_fn = oph_raw_signatures
-    if cand_subbands is None or fine_margin is None:
-        from advanced_scrapper_tpu.config import DedupConfig
-
-        if cand_subbands is None:
-            cand_subbands = DedupConfig().cand_subbands
-        if fine_margin is None:
-            fine_margin = DedupConfig().fine_margin
-    n_seg = num_articles + 1  # +1 scratch row for padding blocks
-
-    def local_step(tokens, lengths, owners):
-        # tokens: uint8[B/n, L] local block shard; owners: int32[B/n] global
-        block_sig = _sig_fn(tokens, lengths, params)
-        # fused combine: local segment-min, then min across shards — blocks
-        # of one article may land on different shards and still fold exactly
-        part = jax.ops.segment_min(block_sig, owners, num_segments=n_seg)
-        sig = jax.lax.pmin(part, data)[:num_articles]
-        if use_oph:
-            sig = densify(sig)
-        blk_valid = (lengths >= k).astype(jnp.int32)
-        v_part = jax.ops.segment_max(blk_valid, owners, num_segments=n_seg)
-        valid = jax.lax.pmax(v_part, data)[:num_articles] > 0
-        keys = band_keys(sig, salt)
-        all_keys = candidate_keys(sig, salt, cand_subbands)
-        rep_bands = duplicate_rep_bands(all_keys, valid)
-        if cand_subbands and fine_margin:
-            thr = fine_edge_thresholds(
-                rep_bands, all_keys, threshold, fine_margin,
-                num_coarse=params.num_bands,
-            )
-        else:
-            thr = jnp.float32(threshold)
-        rep = resolve_rep_bands(
-            rep_bands, sig, valid, thr, jump_rounds=jump_rounds
-        )
-        hist = bucket_histogram(keys, valid, nbins=hist_bins)
-        return rep, hist
-
-    sharded = jax.shard_map(
-        local_step,
-        mesh=mesh,
-        in_specs=(P(data, None), P(data), P(data)),
-        out_specs=(P(None), P(None)),
-        check_vma=False,
-    )
-    return jax.jit(sharded)
-
-
 def make_seq_sharded_signatures(
     mesh: Mesh,
     params: MinHashParams,

@@ -1,18 +1,10 @@
 """Pod-scale packed dedup: the fused donated tile step over a device mesh.
 
-The repo grew two device dedup paths that had never met:
-
-- the mesh-sharded combine (:func:`parallel.sharded.make_sharded_block_dedup`)
-  — shard-local ``segment_min`` partials combined with ``lax.pmin`` — which
-  still rode the OLD transport: three serialized puts and two unfused,
-  undonated dispatches per tile;
-- the single-dispatch plane (``ops.minhash.make_fused_tile_step`` +
-  ``pipeline/dispatch.py``) — ONE packed ``device_put`` and ONE fused
-  donated dispatch per tile, launch-count-asserted — which was
-  single-device only.
-
-This module is their unification: the PR 9 fused tile step *lifted into a
-shard_map/pjit call over the mesh*.  The running accumulator is a global
+This module is the single-dispatch plane (``ops.minhash.
+make_fused_tile_step`` + ``pipeline/dispatch.py`` — ONE packed
+``device_put`` and ONE fused donated dispatch per tile,
+launch-count-asserted) *lifted into a shard_map/pjit call over the
+mesh*.  The running accumulator is a global
 ``uint32[n_shards, num_articles, P]`` array sharded one row per device; a
 tile group is a global ``uint8[n_shards, rows*(width+8)]`` packed buffer
 assembled from per-shard ``jax.device_put``\\ s (one put per shard per
@@ -29,9 +21,9 @@ accumulator update survive partitioning); it is asserted per corpus via
 
 The cross-shard combine happens ONCE, at end of corpus, in the resolve
 epilogue: shard partials meet with ``lax.pmin`` over every mesh axis
-(MinHash's min-algebra makes the blockwise + sharded combine exact —
-identical math to ``make_sharded_block_dedup``, moved from per-dispatch
-to per-corpus), then the standard LSH resolution runs replicated.  Band
+(MinHash's min-algebra makes the blockwise + sharded combine exact, so
+one combine per corpus suffices), then the standard LSH resolution runs
+replicated.  Band
 keys for the persistent-index plane come off the same combined signatures
 (:func:`make_sharded_keys_epilogue`); the *cross-shard band-key merge*
 itself rides the index fleet (``index/fleet.py``) as the host-side plane.
@@ -231,9 +223,8 @@ def make_sharded_resolve_epilogue(
     end-of-corpus dispatch of the sharded packed plane.
 
     This is where the cross-shard combine lives: shard partials meet with
-    ``lax.pmin`` over every mesh axis (exactly the
-    ``make_sharded_block_dedup`` combine, hoisted from per-dispatch to
-    per-corpus), the OPH densify runs once AFTER it, and the standard
+    ``lax.pmin`` over every mesh axis (once per corpus, not per
+    dispatch), the OPH densify runs once AFTER it, and the standard
     estimator-only LSH resolution (coarse+fine candidate keys → per-band
     candidates → optional per-edge fine bars → verified union-find)
     follows, replicated on every shard — identical math to

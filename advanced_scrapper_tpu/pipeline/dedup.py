@@ -198,8 +198,9 @@ class NearDupEngine:
             shingle_k=self.cfg.shingle_k,
             seed=self.cfg.seed,
         )
-        # compiled fused-step cache for dedup_reps_sharded, keyed on
-        # (mesh, article bucket, block_len) — meshes are hashable
+        # the sharded plane's compiled programs (tile step, accumulator
+        # init, resolve and keys epilogues), keyed on (mesh, kind) —
+        # meshes are hashable
         self._sharded_steps: dict = {}
         #: the single-dispatch packed tile step (ops.minhash.
         #: make_fused_tile_step), built lazily — params constant-fold in
@@ -250,10 +251,8 @@ class NearDupEngine:
         self._instrument()
         # ASTPU_DEDUP_PREWARM=N; initialises jax.  1 = default (one
         # batch_size corpus); >1 pins the expected per-corpus article
-        # count, whose bucket the step set keys on.  Pointless under the
-        # legacy transport (it never dispatches the fused step), so the
-        # escape-hatch combination skips it instead of burning compiles.
-        if self.cfg.prewarm and self.cfg.packed_h2d:
+        # count, whose bucket the step set keys on.
+        if self.cfg.prewarm:
             self.prewarm(None if self.cfg.prewarm == 1 else self.cfg.prewarm)
 
     def _instrument(self) -> None:
@@ -583,10 +582,7 @@ class NearDupEngine:
           a compressing transport);
         - each tile crosses the boundary as ONE packed ``device_put``
           (``ops/pack.py``) and ONE fused jitted dispatch with the
-          accumulator donated (``ops.minhash.make_fused_tile_step``) —
-          down from three serialized puts + two dispatches per tile
-          (``cfg.packed_h2d=False`` restores that legacy transport, kept
-          byte-identical for parity certification);
+          accumulator donated (``ops.minhash.make_fused_tile_step``);
         - puts queue ahead of compute (async dispatch, no host sync until
           the caller materialises a result), and the
           encode→pack→put→dispatch stages run pipelined with a bounded
@@ -606,7 +602,7 @@ class NearDupEngine:
         maybe_enable_compile_cache()
 
         from advanced_scrapper_tpu.obs import stages, trace
-        from advanced_scrapper_tpu.ops.minhash import accumulate_block_signatures
+        from advanced_scrapper_tpu.ops.pack import pack_tile
         from advanced_scrapper_tpu.ops.shingle import U32_MAX
 
         tid = trace_id or trace.new_trace_id()
@@ -625,104 +621,66 @@ class NearDupEngine:
         # The min-combine is order-independent, so out-of-order arrival
         # from the pool never matters.
         from advanced_scrapper_tpu.obs import devprof
-        from advanced_scrapper_tpu.pipeline.dispatch import PipelinedDispatcher
+        from advanced_scrapper_tpu.pipeline.dispatch import (
+            PipelinedDispatcher,
+            dispatch_with_oom_backoff,
+        )
 
         put_workers = resolve_put_workers(cfg)
-        packed_mode = cfg.packed_h2d
         probe = self.dispatch_probe
+        step = self._get_fused_step()
 
-        if packed_mode:
-            from advanced_scrapper_tpu.ops.pack import pack_tile
+        def pack(batch):
+            t, l, o = batch
+            with stages.timed("encode"):  # host memcpy: encode plane
+                return pack_tile(t, l, o), t.shape[0], t.shape[1]
 
-            step = self._get_fused_step()
+        def put(item):
+            buf, rows, w = item
+            t0 = time.perf_counter()
+            with stages.timed("h2d"):
+                dev = jax.device_put(buf)
+            stages.count_device_put(buf.nbytes, "dedup")
+            return dev, rows, w, buf.nbytes, time.perf_counter() - t0
 
-            def pack(batch):
-                t, l, o = batch
-                with stages.timed("encode"):  # host memcpy: encode plane
-                    return pack_tile(t, l, o), t.shape[0], t.shape[1]
+        def dispatch(running, item):
+            dev, rows, w, _nb, _pms = item
+            # latency ledger: per-dispatch submit cost by kernel/shape
+            with devprof.dispatch_span(
+                "dedup_fused_tile", rows=rows, width=w, trace=tid
+            ):
+                out = step(
+                    running, dev, rows=rows, width=w, num_articles=n_bucket
+                )
+            # counted on success, INSIDE the fn: the OOM-backoff
+            # ladder then ledgers exactly its leaf dispatches
+            stages.count_dispatch("dedup")
+            return out
 
-            def put(item):
-                buf, rows, w = item
-                t0 = time.perf_counter()
+        def split_packed(item):
+            """Device-OOM halving: D2H the packed buffer, re-pack as
+            two half-row tiles, re-put.  Each sub-item carries its
+            TRUE row count — on an odd-row tile (non-power-of-two
+            ``block_len`` configs) the halves differ by one row, and
+            a mislabeled count would shift the trailer decode.  For
+            the default power-of-two shapes the halves stay inside
+            the prewarmed set (no recompile storm); odd shapes may
+            compile a backoff variant once.  The extra puts/bytes
+            land on the always-on device ledger like any transfer."""
+            dev, rows, w, _nb, _pms = item
+            buf = np.asarray(dev)
+            tok = buf[: rows * w].reshape(rows, w)
+            trailer = buf[rows * w :].view("<i4").reshape(2, rows)
+            half = rows // 2
+            out = []
+            for lo, hi in ((0, half), (half, rows)):
+                sl = slice(lo, hi)
+                pb = pack_tile(tok[sl], trailer[0, sl], trailer[1, sl])
                 with stages.timed("h2d"):
-                    dev = jax.device_put(buf)
-                stages.count_device_put(buf.nbytes, "dedup")
-                return dev, rows, w, buf.nbytes, time.perf_counter() - t0
-
-            def dispatch(running, item):
-                dev, rows, w, _nb, _pms = item
-                # latency ledger: per-dispatch submit cost by kernel/shape
-                with devprof.dispatch_span(
-                    "dedup_fused_tile", rows=rows, width=w, trace=tid
-                ):
-                    out = step(
-                        running, dev, rows=rows, width=w, num_articles=n_bucket
-                    )
-                # counted on success, INSIDE the fn: the OOM-backoff
-                # ladder then ledgers exactly its leaf dispatches
-                stages.count_dispatch("dedup")
-                return out
-
-            def split_packed(item):
-                """Device-OOM halving: D2H the packed buffer, re-pack as
-                two half-row tiles, re-put.  Each sub-item carries its
-                TRUE row count — on an odd-row tile (non-power-of-two
-                ``block_len`` configs) the halves differ by one row, and
-                a mislabeled count would shift the trailer decode.  For
-                the default power-of-two shapes the halves stay inside
-                the prewarmed set (no recompile storm); odd shapes may
-                compile a backoff variant once.  The extra puts/bytes
-                land on the always-on device ledger like any transfer."""
-                dev, rows, w, _nb, _pms = item
-                buf = np.asarray(dev)
-                tok = buf[: rows * w].reshape(rows, w)
-                trailer = buf[rows * w :].view("<i4").reshape(2, rows)
-                half = rows // 2
-                out = []
-                for lo, hi in ((0, half), (half, rows)):
-                    sl = slice(lo, hi)
-                    pb = pack_tile(tok[sl], trailer[0, sl], trailer[1, sl])
-                    with stages.timed("h2d"):
-                        d = jax.device_put(pb)
-                    stages.count_device_put(pb.nbytes, "dedup")
-                    out.append((d, hi - lo, w, pb.nbytes, 0.0))
-                return out
-        else:
-            # legacy tile transport (parity certification / escape hatch):
-            # three serialized puts + two dispatches per tile, same bytes
-            block_fn = resolve_signature_fn(cfg.backend)
-            if use_oph:
-                from advanced_scrapper_tpu.ops.oph import oph_raw_signatures
-
-                block_fn = oph_raw_signatures  # densify AFTER the combine
-
-            def pack(batch):
-                t, l, o = batch
-                return t, l, o, t.nbytes + l.nbytes + o.nbytes
-
-            def put(item):
-                t, l, o, nbytes = item
-                t0 = time.perf_counter()
-                with stages.timed("h2d"):
-                    t, l, o = (
-                        jax.device_put(t), jax.device_put(l), jax.device_put(o)
-                    )
-                for arr in (t, l, o):
-                    stages.count_device_put(arr.nbytes, "dedup")
-                return t, l, o, nbytes, time.perf_counter() - t0
-
-            def dispatch(running, item):
-                t, l, o, _nb, _pms = item
-                stages.count_dispatch("dedup")  # block_fn; the fold below
-                with devprof.dispatch_span(
-                    "dedup_legacy_tile",
-                    rows=int(t.shape[0]), width=int(t.shape[1]), trace=tid,
-                ):
-                    out = accumulate_block_signatures(
-                        running, block_fn(t, l, params), o,
-                        num_articles=n_bucket,
-                    )
-                return out
+                    d = jax.device_put(pb)
+                stages.count_device_put(pb.nbytes, "dedup")
+                out.append((d, hi - lo, w, pb.nbytes, 0.0))
+            return out
 
         running = jnp.full((n_bucket, params.num_perm), U32_MAX, jnp.uint32)
         dispatched = 0
@@ -745,39 +703,28 @@ class NearDupEngine:
             put_workers=put_workers,
             window=window,
         )
-        from advanced_scrapper_tpu.pipeline.dispatch import (
-            dispatch_with_oom_backoff,
-        )
-
         try:
             for item in pipe:
-                rows = int(item[0].shape[0]) if not packed_mode else item[1]
+                _dev, rows, w, nbytes, put_s = item
                 t0 = time.perf_counter()
                 with stages.timed("kernel"), self.step_timer.step(rows):
-                    # async dispatch; device waits land here
-                    if packed_mode:
-                        # RESOURCE_EXHAUSTED halves the tile (re-pack,
-                        # re-put, retry — byte-identical fold) down to
-                        # the 64-row floor, then fails cleanly
-                        running = dispatch_with_oom_backoff(
-                            dispatch, running, item,
-                            split=split_packed,
-                            rows_of=lambda it: it[1],
-                        )
-                    else:
-                        running = dispatch(running, item)
-                if not packed_mode:
-                    stages.count_dispatch("dedup")
+                    # async dispatch; device waits land here.
+                    # RESOURCE_EXHAUSTED halves the tile (re-pack, re-put,
+                    # retry — byte-identical fold) down to the 64-row
+                    # floor, then fails cleanly
+                    running = dispatch_with_oom_backoff(
+                        dispatch, running, item,
+                        split=split_packed,
+                        rows_of=lambda it: it[1],
+                    )
                 if probe is not None:
                     probe(
                         {
                             "tile": dispatched,
                             "rows": rows,
-                            "width": int(
-                                item[2] if packed_mode else item[0].shape[1]
-                            ),
-                            "h2d_bytes": int(item[-2]),
-                            "put_ms": round(item[-1] * 1e3, 3),
+                            "width": int(w),
+                            "h2d_bytes": int(nbytes),
+                            "put_ms": round(put_s * 1e3, 3),
                             "dispatch_ms": round(
                                 (time.perf_counter() - t0) * 1e3, 3
                             ),
@@ -1299,21 +1246,12 @@ class NearDupEngine:
         :meth:`dedup_reps` when the exact-verify precision path is
         required).
 
-        Default (``cfg.packed_h2d``): the PACKED plane — the shared
-        width-bucketed chunker feeds per-shard packed single-put tiles
-        through the pipelined executor into one partitioned fused
-        donated dispatch per tile group (1 put + 1 dispatch per tile per
-        shard, shard-labelled on the always-on ledger), with the
-        cross-shard ``pmin`` combine + LSH resolution as one end-of-corpus
-        epilogue dispatch.  ``ASTPU_DEDUP_PACKED_H2D=0`` restores the
-        legacy unpacked transport (blockwise ``encode_blocks`` →
-        ``make_sharded_block_dedup``), kept byte-identical as the parity
-        oracle."""
-        if self.cfg.packed_h2d:
-            return self._dedup_reps_sharded_packed(texts, mesh)
-        return self._dedup_reps_sharded_legacy(texts, mesh)
-
-    def _dedup_reps_sharded_packed(self, texts, mesh) -> np.ndarray:
+        The shared width-bucketed chunker feeds per-shard packed
+        single-put tiles through the pipelined executor into one
+        partitioned fused donated dispatch per tile group (1 put + 1
+        dispatch per tile per shard, shard-labelled on the always-on
+        ledger), with the cross-shard ``pmin`` combine + LSH resolution
+        as one end-of-corpus epilogue dispatch."""
         from advanced_scrapper_tpu.obs import stages, trace
         from advanced_scrapper_tpu.parallel.sharded_packed import (
             local_shard_rows,
@@ -1364,69 +1302,6 @@ class NearDupEngine:
             "dedup.readback", trace=tid, docs=len(raw)
         ):  # readback sync: the device drains here
             return np.asarray(keys_dev)[: len(raw)]
-
-    def _dedup_reps_sharded_legacy(self, texts, mesh) -> np.ndarray:
-        """The PR 2 unpacked sharded transport — blockwise encode →
-        ``make_sharded_block_dedup`` (three arrays H2D, one monolithic
-        dispatch).  Kept byte-identical behind ``ASTPU_DEDUP_PACKED_H2D=0``
-        as the packed plane's parity oracle (MIGRATION: new callers use
-        the packed entry)."""
-        from advanced_scrapper_tpu.obs import stages, trace
-        from advanced_scrapper_tpu.parallel.sharded import (
-            make_sharded_block_dedup,
-        )
-
-        n = len(texts)
-        if n == 0:
-            return np.zeros((0,), np.int32)
-        tid = trace.new_trace_id()
-        self._m_docs["sharded"].inc(n)
-        cfg = self.cfg
-        raw = [to_bytes(t) for t in texts]
-        with stages.timed("encode"):
-            tok, lens, owners = encode_blocks(
-                raw, cfg.block_len, overlap=self.params.shingle_k - 1
-            )
-            owners = owners.astype(np.int32)
-            n_bucket = bucket_len(n, min_bucket=64)
-            # shard divisibility + bucketed block axis: pad rows to the
-            # scratch article slot (owner n_bucket → sliced off on device)
-            ndev = int(np.prod([mesh.shape[a] for a in mesh.axis_names]))
-            rows = bucket_len(max(tok.shape[0], ndev), min_bucket=64)
-            rows = -(-rows // ndev) * ndev  # exact multiple for odd meshes
-            if tok.shape[0] < rows:
-                pad = rows - tok.shape[0]
-                tok = np.concatenate(
-                    [tok, np.zeros((pad, cfg.block_len), np.uint8)]
-                )
-                lens = np.concatenate([lens, np.zeros((pad,), np.int32)])
-                owners = np.concatenate(
-                    [owners, np.full((pad,), n_bucket, np.int32)]
-                )
-        key = (mesh, n_bucket, cfg.block_len)
-        step = self._sharded_steps.get(key)
-        if step is None:
-            step = make_sharded_block_dedup(
-                mesh,
-                self.params,
-                n_bucket,
-                threshold=cfg.sim_threshold,
-                jump_rounds=_jump_rounds(n_bucket),
-                backend=cfg.backend,
-                cand_subbands=cfg.cand_subbands,
-                fine_margin=cfg.fine_margin,
-            )
-            self._sharded_steps[key] = step
-        with self.step_timer.step(int(tok.shape[0])):
-            rep, _hist = step(tok, lens, owners)
-        stages.count_dispatch("dedup")
-        self._m_batches.inc()
-        with stages.timed("resolve"), trace.span(
-            "dedup.resolve", trace=tid, regime="sharded", docs=n
-        ):
-            out = np.asarray(rep)[:n]
-        self._count_result("sharded", n, out)
-        return out
 
     def _exact_verified_ok(self, raw, sigs, keys, valid, rep_bands):
         """Verified-edge matrix with statistically fragile edges confirmed
@@ -1806,9 +1681,7 @@ class NearDupEngine:
         cross-shard band-key merge then rides the index plane on the
         host: a ``ShardedIndexClient`` fans each key to its ring shard
         (probe row-min + replicated insert), which is deliberately
-        decoupled from the device-mesh shard count.  (With the legacy
-        transport forced — ``ASTPU_DEDUP_PACKED_H2D=0`` — ``mesh`` is
-        ignored: the oracle transport has no sharded keys plane.)
+        decoupled from the device-mesh shard count.
         """
         from advanced_scrapper_tpu.utils.bloom import pack_keys64
 
@@ -1820,7 +1693,7 @@ class NearDupEngine:
         # fused epilogue: the wide keys come off the device-resident
         # accumulator in one dispatch — signatures never bounce D2H→H2D,
         # and are never synced at all (the index stores keys only)
-        if mesh is not None and self.cfg.packed_h2d:
+        if mesh is not None:
             keys_wide = self._keys_wide_sharded(raw, mesh)
         else:
             _sigs, keys_wide = self.signatures_and_keys(

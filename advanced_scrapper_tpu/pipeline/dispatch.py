@@ -14,8 +14,7 @@ screen plane (``pipeline/matcher.py``, independent per-tile masks) and
 the MESH-SHARDED dedup plane (a sharded source on the same graph: each
 "tile" is a per-shard group whose ``put`` issues one ``device_put`` per
 shard and whose dispatch is one partitioned fused step —
-``parallel/sharded_packed.py``) ride the same three stages, as does the
-legacy multi-array tile transport kept alive for parity certification —
+``parallel/sharded_packed.py``) ride the same three stages —
 ``pack``/``put`` are caller-supplied callables, the executor knows
 nothing of any workload:
 

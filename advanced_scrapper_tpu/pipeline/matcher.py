@@ -52,10 +52,7 @@ pipelined encode/pack → h2d → dispatch through the dispatch executor
 is exactly 1 put + 1 dispatch, gated numerically by the always-on
 device counters (tier-1 ``tests/test_match_dispatch.py``, ``bench
 --regime matcher``).  The refine race picks fused-vs-screen-only
-MODES of that one step, not separate kernels.  ``ASTPU_MATCH_PACKED=0``
-keeps the legacy per-batch screen loop (``_legacy_screen``) runnable —
-byte-identical output, certified across screen-only / refine /
-overlong-fallback / pooled-verify modes.
+MODES of that one step, not separate kernels.
 
 Documented divergences from the reference (both are reference *crashes*):
 - a fuzzy-matched name that is itself an invalid regex falls back to
@@ -234,7 +231,6 @@ class EntityIndex:
                         )
                     # pure-lowercase-alpha names are skipped (ref :174)
         self._tables: dict | None = None
-        self._refine_tables: tuple | None = None
         self._verify_arena = None
         self._upper_matcher: tuple | None = None
         #: compiled packed screen steps per mode (False = screen-only,
@@ -479,12 +475,8 @@ def _refine_candidates(index: EntityIndex):
     """Fuzzy names the Myers bound kernel can handle: non-exact-upper,
     1..MAX_PATTERN bytes, pure ASCII (the bound is byte-level; multi-byte
     chars would break its soundness vs the char-level oracle).  Returns
-    ``(name_indices, names, mask_tables)``, cached on the index (same
-    lifetime as ``screen_tables`` — the tables depend only on the index,
-    never on the chunk)."""
-    cached = getattr(index, "_refine_tables", None)
-    if cached is not None:
-        return cached
+    ``(name_indices, mask_tables)``; the tables depend only on the index,
+    and ``_screen_steps`` builds them once per index."""
     from advanced_scrapper_tpu.ops.editdist import MAX_PATTERN, build_pattern_masks
 
     ix, names = [], []
@@ -493,9 +485,7 @@ def _refine_candidates(index: EntityIndex):
         if not e.is_exact_upper and 0 < len(nb) <= MAX_PATTERN and nb.isascii():
             ix.append(j)
             names.append(nb)
-    out = (np.asarray(ix, dtype=np.int64), names, build_pattern_masks(names))
-    index._refine_tables = out
-    return out
+    return np.asarray(ix, dtype=np.int64), build_pattern_masks(names)
 
 
 class RefineController:
@@ -562,70 +552,6 @@ class RefineController:
         return on < off * self.WIN_MARGIN
 
 
-def _refine_batch(
-    batch,
-    got: np.ndarray,
-    overlong,
-    fuzzy_ix: np.ndarray,
-    fuzzy_names: list,
-    mask_tables,
-    threshold: float,
-    *,
-    max_pairs: int = 1024,
-) -> list[set | None]:
-    """Per-row sets of name indices whose text-side score is device-proven
-    ≤ threshold.  Non-ASCII texts pass through (byte/char mismatch).
-    Zero surviving pairs → no device dispatch at all."""
-    from advanced_scrapper_tpu.core.tokenizer import encode_batch
-    from advanced_scrapper_tpu.ops.editdist import prune_mask_tables
-
-    name_lens = np.array([len(n) for n in fuzzy_names], dtype=np.int64)
-    pair_row: list[int] = []
-    pair_k: list[int] = []
-    for i, (text, _title, _d, _r) in enumerate(batch):
-        if overlong[i] or not text or not text.isascii():
-            continue
-        # strictly longer only: equal-length pairs are never prunable under
-        # rapidfuzz's bidirectional rule (see editdist.prune_mask_tables)
-        sel = np.nonzero(got[i][fuzzy_ix] & (len(text) > name_lens))[0]
-        pair_row.extend([i] * len(sel))
-        pair_k.extend(sel.tolist())
-    out: list[set | None] = [None] * len(batch)
-    if not pair_row:
-        return out
-    row_ids = sorted(set(pair_row))
-    pos = {r: k for k, r in enumerate(row_ids)}
-    tok, ln = encode_batch([batch[r][0] for r in row_ids])
-    from advanced_scrapper_tpu.obs import stages
-
-    for start in range(0, len(pair_row), max_pairs):
-        rows_s = pair_row[start : start + max_pairs]
-        ks = pair_k[start : start + max_pairs]
-        # pad the slice to a fixed pair count: the jitted kernel would
-        # otherwise recompile for every distinct remainder size
-        pad = max_pairs - len(rows_s)
-        t_ix = np.array([pos[r] for r in rows_s] + [pos[rows_s[0]]] * pad)
-        ks_p = np.array(ks + [ks[0]] * pad)
-        t_slice, l_slice = tok[t_ix], ln[t_ix]
-        # ledger instrumentation: the slice's jit args (gathered texts +
-        # the per-pair mask gather) ARE this path's tile traffic — count
-        # them as the two dominant puts plus the bound dispatch so the
-        # packed path's 1+1 contract is a measured subtraction against
-        # comparable legacy numbers
-        stages.count_device_put(t_slice.nbytes, "matcher")
-        stages.count_device_put(len(ks_p) * 256 * 4, "matcher")
-        stages.count_dispatch("matcher")
-        pruned = prune_mask_tables(
-            mask_tables, t_slice, l_slice, ks_p, threshold
-        )
-        for r, k, p in zip(rows_s, ks, pruned):
-            if p:
-                if out[r] is None:
-                    out[r] = set()
-                out[r].add(int(fuzzy_ix[k]))
-    return out
-
-
 # -- packed single-dispatch screen tiles (the PR 9 plane) --------------------
 
 
@@ -670,7 +596,7 @@ def _screen_steps(index: EntityIndex, use_refine: bool):
         with stages.timed("matcher_build"):
             refine = None
             if key:
-                fuzzy_ix, _names, mask_tables = _refine_candidates(index)
+                fuzzy_ix, mask_tables = _refine_candidates(index)
                 if len(fuzzy_ix):
                     masks, lens, ok = mask_tables
                     refine = (masks, lens, ok, fuzzy_ix)
@@ -855,8 +781,8 @@ def _packed_screen(
                 prunes[a].add(int(c))
 
     # Mask readback trails the dispatch loop by a bounded LAG (the
-    # executor's own residency bound) instead of syncing per tile (the
-    # legacy loop's stall) or deferring every tile to end-of-chunk: a
+    # executor's own residency bound) instead of syncing per tile (a
+    # stall per tile) or deferring every tile to end-of-chunk: a
     # 20k-row chunk against a large entity index would otherwise hold
     # O(tiles) [rows, N] device masks at once.  Syncing a tile that is
     # `lag` dispatches behind costs ~nothing — it has almost surely
@@ -906,100 +832,6 @@ def _packed_screen(
             scatter(result)
     waits.flush()
     return masks, prunes
-
-
-def _legacy_screen(
-    rows: list,
-    index: EntityIndex,
-    *,
-    use_refine: bool,
-    threshold: float,
-    screen_batch: int,
-    screen_block: int,
-) -> tuple[list, list]:
-    """The pre-packed screen loop (``ASTPU_MATCH_PACKED=0``): fixed
-    ``screen_batch`` batches, separate screen and Myers-bound dispatches.
-    Kept byte-identical as the parity oracle and escape hatch, and
-    INSTRUMENTED — its per-batch device traffic (4 array puts + 1 screen
-    dispatch, plus the refine slices' transfers) lands in the same
-    always-on counters as the packed path, so the launch-count drop is a
-    measured subtraction, not prose."""
-    import jax
-
-    from advanced_scrapper_tpu.core.tokenizer import bucket_len, encode_batch
-    from advanced_scrapper_tpu.obs import devprof, stages, telemetry
-    from advanced_scrapper_tpu.ops.match import match_screen
-
-    tables = index.screen_tables()
-    fuzzy_ix, fuzzy_names, mask_tables = (
-        _refine_candidates(index) if use_refine else (np.array([]), [], None)
-    )
-    masks: list[np.ndarray | None] = [None] * len(rows)
-    text_prunes: list[set | None] = [None] * len(rows)
-    n_overlong = 0
-    for start in range(0, len(rows), screen_batch):
-        batch = rows[start : start + screen_batch]
-        with stages.timed("matcher_screen"):
-            # bitmap over title+text; part lengths drive the soundness
-            # bounds
-            raw = [
-                (title + "\n" + text).encode("utf-8", "replace")
-                for text, title, _, _ in batch
-            ]
-            text_len = np.array(
-                [len(t.encode("utf-8", "replace")) for t, _, _, _ in batch],
-                np.int32,
-            )
-            title_len = np.array(
-                [len(t.encode("utf-8", "replace")) for _, t, _, _ in batch],
-                np.int32,
-            )
-            overlong = [len(r) > screen_block for r in raw]
-            n_overlong += sum(overlong)
-            # ``screen_block`` is a CAP, not the tile width: the batch
-            # encodes at the longest article's power-of-two bucket, so a
-            # 2 kB news corpus screens on 2 kB rows instead of paying the
-            # 64 kB worst case (measured 88% of matcher wall time was
-            # screening zero padding).  O(log) compiled screen shapes.
-            blk = bucket_len(
-                max(len(r) for r in raw), min_bucket=1024,
-                max_bucket=screen_block,
-            )
-            tok, ln = encode_batch(raw, block_len=blk)
-        # puts land in h2d ONLY (matching the packed path's put stage) —
-        # nesting them inside matcher_screen would double-count transfer
-        # time into the exact stage the packed-vs-legacy A/B compares
-        with stages.timed("h2d"):
-            tok_d = jax.device_put(tok)
-            tl_d = jax.device_put(text_len)
-            ttl_d = jax.device_put(title_len)
-            ln_d = jax.device_put(ln)
-        for arr in (tok, text_len, title_len, ln):
-            stages.count_device_put(arr.nbytes, "matcher")
-        with stages.timed("matcher_screen"), devprof.dispatch_span(
-            "matcher_screen_legacy",
-            rows=int(tok.shape[0]), width=int(tok.shape[1]),
-        ):
-            got = match_screen(
-                tok_d, tl_d, ttl_d, ln_d, tables, threshold=threshold
-            )
-            stages.count_dispatch("matcher")
-        for i in range(len(batch)):
-            # articles longer than the screen block fall back to full scan
-            masks[start + i] = None if overlong[i] else got[i]
-        if len(fuzzy_ix):
-            prunes = _refine_batch(
-                batch, got, overlong, fuzzy_ix, fuzzy_names, mask_tables,
-                threshold,
-            )
-            for i, pr in enumerate(prunes):
-                text_prunes[start + i] = pr
-    if n_overlong:
-        telemetry.event_counter(
-            "astpu_matcher_overlong_total",
-            "articles above screen_block routed to the full host scan",
-        ).inc(n_overlong)
-    return masks, text_prunes
 
 
 def prewarm_screen(
@@ -1058,11 +890,9 @@ def match_chunk_async(
     *,
     use_screen: bool = True,
     use_refine: bool | str = "auto",
-    screen_batch: int = 128,
     screen_block: int = 1 << 16,
     threshold: float = 95.0,
     pool=None,
-    packed: bool | None = None,
     screen_tile_bytes: int | None = None,
     dispatch_window: int | None = None,
     screen_put_workers: int | None = None,
@@ -1127,41 +957,26 @@ def match_chunk_async(
         with trace.span("matcher.screen", trace=tid, articles=len(rows)):
             # knob resolution: explicit args win, else the ASTPU_MATCH_* env
             # (run_matcher passes its MatchConfig fields through explicitly)
-            if None in (
-                packed, screen_tile_bytes, dispatch_window, screen_put_workers
-            ):
+            if None in (screen_tile_bytes, dispatch_window, screen_put_workers):
                 _cfg = _match_cfg()
-                packed = _cfg.packed if packed is None else packed
                 if screen_tile_bytes is None:
                     screen_tile_bytes = _cfg.screen_tile_bytes
                 if dispatch_window is None:
                     dispatch_window = _cfg.dispatch_window
                 if screen_put_workers is None:
                     screen_put_workers = _cfg.put_workers
-            screen_put_workers = screen_put_workers or 1
-            if packed:
-                # the packed plane: byte-budget width-bucketed tiles, ONE packed
-                # put + ONE fused screen(+bound) dispatch per tile, pipelined
-                # (retired screen_batch is ignored here — MIGRATION.md)
-                masks, text_prunes = _packed_screen(
-                    rows,
-                    index,
-                    use_refine=bool(use_refine),
-                    threshold=threshold,
-                    screen_block=screen_block,
-                    tile_bytes=screen_tile_bytes,
-                    window=dispatch_window,
-                    put_workers=screen_put_workers,
-                )
-            else:
-                masks, text_prunes = _legacy_screen(
-                    rows,
-                    index,
-                    use_refine=bool(use_refine),
-                    threshold=threshold,
-                    screen_batch=screen_batch,
-                    screen_block=screen_block,
-                )
+            # byte-budget width-bucketed tiles, ONE packed put + ONE
+            # fused screen(+bound) dispatch per tile, pipelined
+            masks, text_prunes = _packed_screen(
+                rows,
+                index,
+                use_refine=bool(use_refine),
+                threshold=threshold,
+                screen_block=screen_block,
+                tile_bytes=screen_tile_bytes,
+                window=dispatch_window,
+                put_workers=screen_put_workers or 1,
+            )
 
     if pool is not None and len(rows) > 1:
         # ship (text, title, date, row-INDEX) out; the full row record stays
@@ -1225,11 +1040,9 @@ def match_chunk(
     *,
     use_screen: bool = True,
     use_refine: bool | str = "auto",
-    screen_batch: int = 128,
     screen_block: int = 1 << 16,
     threshold: float = 95.0,
     pool=None,
-    packed: bool | None = None,
     screen_tile_bytes: int | None = None,
     dispatch_window: int | None = None,
     screen_put_workers: int | None = None,
@@ -1251,11 +1064,9 @@ def match_chunk(
         index,
         use_screen=use_screen,
         use_refine=use_refine,
-        screen_batch=screen_batch,
         screen_block=screen_block,
         threshold=threshold,
         pool=pool,
-        packed=packed,
         screen_tile_bytes=screen_tile_bytes,
         dispatch_window=dispatch_window,
         screen_put_workers=screen_put_workers,
@@ -1420,10 +1231,9 @@ def run_matcher(
         raise ValueError("use_refine requires use_screen (see DESIGN.md §4)")
     if workers is None:
         workers = cfg.verify_workers
-    if cfg.prewarm and cfg.packed and use_screen and index.entries:
+    if cfg.prewarm and use_screen and index.entries:
         # compile the screen-step shape set before the first chunk (the
-        # NearDupEngine.prewarm twin; pointless under the legacy loop,
-        # which never dispatches the packed step).  Under "auto" both
+        # NearDupEngine.prewarm twin).  Under "auto" both
         # modes compile so the refine race can flip without a mid-stream
         # compile stall; a forced mode prewarms only the mode that can
         # ever dispatch.
@@ -1488,7 +1298,6 @@ def run_matcher(
             use_refine=mode,
             threshold=cfg.fuzzy_threshold,
             pool=pool,
-            packed=cfg.packed,
             screen_tile_bytes=cfg.screen_tile_bytes,
             dispatch_window=cfg.dispatch_window,
             screen_put_workers=cfg.put_workers,

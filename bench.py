@@ -27,17 +27,12 @@ Sweep knobs (env):
   ASTPU_BENCH_FEED_WORKERS=N  DeviceFeed put threads for the stream regime
   ASTPU_DEDUP_PUT_WORKERS=N   H2D put threads in the dispatch executor
   ASTPU_DEDUP_DISPATCH_WINDOW=N  in-flight tile window depth (0 = auto)
-  ASTPU_DEDUP_PACKED_H2D=0    legacy 3-put/2-dispatch tile transport
-                              (parity escape hatch; default = packed)
   ASTPU_DEDUP_RERANK=0|1      precision rerank tier on/off — wins over
                               every regime pin (throughput regimes pin
                               it OFF for bench-history comparability;
                               the rerank regime pins it ON)
   ASTPU_DEDUP_RERANK_TILE_ROWS=N  settle-tile row budget for the packed
                               pair tiles of the rerank regime
-  ASTPU_MATCH_PACKED=0        legacy per-batch matcher screen loop
-                              (parity escape hatch; default = packed
-                              single-dispatch screen tiles)
   ASTPU_MATCH_DISPATCH_WINDOW=N  matcher screen-tile window depth
   ASTPU_MATCH_SCREEN_TILE_BYTES=N  byte budget per packed screen tile
   ASTPU_BENCH_MESH=DxS        (data, seq) mesh factorisation for the

@@ -54,3 +54,17 @@ def test_env_tuple_coercion(monkeypatch):
 
     monkeypatch.setenv("ASTPU_ENRICH_COOLDOWN_EVERY3", "10,20")
     assert from_env(EnrichConfig, "enrich").cooldown_every3 == (10.0, 20.0)
+
+
+def test_packed_h2d_false_raises():
+    """``packed_h2d`` has one legal value; False (or any non-True value)
+    raises at construction, naming the migration note."""
+    import dataclasses
+
+    import pytest
+
+    with pytest.raises(ValueError, match="MIGRATION.md"):
+        DedupConfig(packed_h2d=False)
+    with pytest.raises(ValueError, match="packed_h2d"):
+        dataclasses.replace(DedupConfig(), packed_h2d=False)
+    assert DedupConfig(packed_h2d=True).packed_h2d is True

@@ -2,10 +2,11 @@
 executor, packed H2D transfers, the donated fused tile step, and the
 always-on device-traffic counters that gate the win numerically.
 
-Certification strategy mirrors the PR 2 host-path overhaul: the packed
-transport is pure performance work, so every byte of output must match
-the legacy 3-put/2-dispatch path — across the one-shot, async, streaming
-(batch backend) and persistent-index modes.
+Certification strategy: output must not depend on the dispatch knobs,
+and the estimator-only resolution must equal the plain numpy references
+(``cpu.oracle.device_family_signatures`` + ``oracle_lsh_reps``) — across
+the one-shot, async, streaming (batch backend) and persistent-index
+modes.
 """
 
 from __future__ import annotations
@@ -36,6 +37,40 @@ def _corpus(rng: np.random.RandomState, n: int) -> list[bytes]:
             ln = int(rng.randint(5, 9000))
         docs.append(rng.randint(32, 127, size=ln, dtype=np.uint8).tobytes())
     return docs
+
+
+#: the estimator-only resolution (precision tier and exact verify off):
+#: the configuration the plain numpy references model
+ESTIMATOR_ONLY = DedupConfig(rerank=False, exact_verify_band=0.0)
+
+
+def _plain_reps(eng: NearDupEngine, docs: list[bytes]) -> np.ndarray:
+    """Plain numpy representatives: the host twin of the device hash
+    family, resolved by the estimator-only LSH reference."""
+    from advanced_scrapper_tpu.cpu.oracle import (
+        device_family_signatures,
+        oracle_lsh_reps,
+    )
+
+    valid = np.fromiter(map(len, docs), np.int64) >= eng.params.shingle_k
+    return oracle_lsh_reps(
+        device_family_signatures(docs, eng.params),
+        eng.params,
+        cand_subbands=eng.cfg.cand_subbands,
+        threshold=eng.cfg.sim_threshold,
+        valid=valid,
+    )
+
+
+def _numpy_fold(tok, lens, owners, params, n_bucket: int) -> np.ndarray:
+    """Per-owner ``np.minimum.at`` fold of per-row host signatures into a
+    fresh all-``U32_MAX`` ``uint32[n_bucket, num_perm]`` accumulator."""
+    from advanced_scrapper_tpu.cpu.oracle import device_family_signatures
+
+    rows = [bytes(tok[i, : lens[i]]) for i in range(tok.shape[0])]
+    acc = np.full((n_bucket, params.num_perm), 0xFFFFFFFF, np.uint32)
+    np.minimum.at(acc, owners, device_family_signatures(rows, params))
+    return acc
 
 
 # -- the executor itself -----------------------------------------------------
@@ -100,51 +135,41 @@ def test_executor_encode_generator_error_fails_graph():
 
 
 def test_per_tile_traffic_one_put_one_dispatch():
-    """Packed path: exactly 1 put + 1 dispatch per tile (plus the
-    per-corpus valid-mask put and epilogue+resolve dispatches); legacy:
-    3 puts + 2 dispatches per tile — asserted via the ALWAYS-ON counters,
-    so the drop is a measured number, not prose."""
+    """Exactly 1 put + 1 dispatch per tile (plus the per-corpus
+    valid-mask put and the fused resolve epilogue dispatch), and the
+    counted bytes are exactly the packed buffers the probe saw —
+    asserted via the ALWAYS-ON counters, so the contract is a measured
+    number, not prose."""
     from advanced_scrapper_tpu.obs import stages
+    from advanced_scrapper_tpu.ops.pack import packed_nbytes
 
     rng = np.random.RandomState(3)
     docs = _corpus(rng, 128)
-
-    def run(cfg):
-        eng = NearDupEngine(cfg)
-        before = stages.device_counters()
-        rep = np.asarray(eng.dedup_reps_async(docs))[: len(docs)]
-        after = stages.device_counters()
-        return (
-            rep,
-            eng.last_tiles,
-            after["device_puts"] - before["device_puts"],
-            after["device_dispatches"] - before["device_dispatches"],
-            after["h2d_bytes"] - before["h2d_bytes"],
-        )
-
     # rerank=False: the traffic ledger here audits the SIGNATURE tile
     # plane; the precision tier's own tiles+1/tiles+1 contract has its
     # dedicated gate in test_rerank_dispatch.py on the "rerank" regime
-    rep_p, tiles_p, puts_p, disp_p, bytes_p = run(
-        DedupConfig(packed_h2d=True, rerank=False)
+    eng = NearDupEngine(DedupConfig(rerank=False))
+    probe: list[dict] = []
+    eng.dispatch_probe = probe.append
+    before = stages.device_counters()
+    rep = np.asarray(eng.dedup_reps_async(docs))[: len(docs)]
+    after = stages.device_counters()
+    puts = after["device_puts"] - before["device_puts"]
+    disp = after["device_dispatches"] - before["device_dispatches"]
+    nbytes = after["h2d_bytes"] - before["h2d_bytes"]
+    tiles = eng.last_tiles
+    assert tiles > 1 and len(probe) == tiles
+    # 1 put/tile + 1 valid-mask put; 1 dispatch/tile + ONE fused resolve
+    # epilogue — tiles × 1 + 1
+    assert puts == tiles + 1, (puts, tiles)
+    assert disp == tiles + 1, (disp, tiles)
+    # each tile ships tokens plus the 8 B/row (length, owner) trailer
+    assert all(
+        t["h2d_bytes"] == packed_nbytes(t["rows"], t["width"]) for t in probe
     )
-    rep_l, tiles_l, puts_l, disp_l, bytes_l = run(
-        DedupConfig(packed_h2d=False, rerank=False)
-    )
-    assert tiles_p == tiles_l and tiles_p > 1
-    # packed async: 1 put/tile + 1 valid-mask put; 1 dispatch/tile + ONE
-    # fused resolve epilogue — tiles × 1 + 1, the ISSUE 9 contract
-    assert puts_p == tiles_p + 1, (puts_p, tiles_p)
-    assert disp_p == tiles_p + 1, (disp_p, tiles_p)
-    # legacy: 3 puts + 2 dispatches per tile, same corpus constants
-    assert puts_l == 3 * tiles_l + 1, (puts_l, tiles_l)
-    assert disp_l == 2 * tiles_l + 1, (disp_l, tiles_l)
-    # the headline drop: ≥2× fewer dispatches, ~3× fewer puts, and the
-    # same payload bytes ride the fewer puts (±8B/row trailer)
-    assert puts_p * 3 <= puts_l + 3
-    assert disp_p * 2 <= disp_l + 1
-    assert bytes_p > 0 and abs(bytes_p - bytes_l) <= 16 * tiles_p
-    assert (rep_p == rep_l).all()
+    n_bucket = 128  # bucket_len(128 docs): the valid mask's bool bytes
+    assert nbytes == sum(t["h2d_bytes"] for t in probe) + n_bucket
+    assert (rep == _plain_reps(eng, docs)).all()
 
 
 # -- donation safety ---------------------------------------------------------
@@ -183,41 +208,30 @@ def test_fused_step_donates_accumulator():
     # the donated buffer is unusable afterwards — referencing it raises
     with pytest.raises(RuntimeError):
         np.asarray(running)
-    # and the fold is bit-exact vs the legacy two-dispatch path
-    from advanced_scrapper_tpu.ops.minhash import (
-        accumulate_block_signatures,
-        minhash_signatures,
-    )
-
-    running2 = jnp.full((n_bucket, params.num_perm), U32_MAX, jnp.uint32)
-    want = accumulate_block_signatures(
-        running2,
-        minhash_signatures(jnp.asarray(tok), jnp.asarray(lens), params),
-        jnp.asarray(owners),
-        num_articles=n_bucket,
-    )
-    assert (np.asarray(out) == np.asarray(want)).all()
+    # and the fold is bit-exact vs a plain per-owner numpy fold of the
+    # host twin of the device hash family
+    want = _numpy_fold(tok, lens, owners, params, n_bucket)
+    assert (np.asarray(out) == want).all()
 
 
-# -- byte-identical output across modes --------------------------------------
-
-
-def _engines():
-    return (
-        NearDupEngine(DedupConfig(packed_h2d=True)),
-        NearDupEngine(DedupConfig(packed_h2d=False)),
-    )
+# -- output against the plain references --------------------------------------
 
 
 def test_packed_parity_oneshot_and_async():
+    """Signatures equal the host twin of the device hash family bit for
+    bit; the estimator-only resolution (one-shot with exact verify off,
+    and async) equals the plain numpy LSH resolution."""
+    from advanced_scrapper_tpu.cpu.oracle import device_family_signatures
+
     rng = np.random.RandomState(11)
     docs = _corpus(rng, 96)
-    new, old = _engines()
-    assert (new.dedup_reps(docs) == old.dedup_reps(docs)).all()
-    a_new = np.asarray(new.dedup_reps_async(docs))
-    a_old = np.asarray(old.dedup_reps_async(docs))
-    assert (a_new == a_old).all()
-    assert (new.signatures(docs) == old.signatures(docs)).all()
+    eng = NearDupEngine(ESTIMATOR_ONLY)
+    want = _plain_reps(eng, docs)
+    assert (eng.dedup_reps(docs) == want).all()
+    assert (np.asarray(eng.dedup_reps_async(docs))[: len(docs)] == want).all()
+    assert (
+        eng.signatures(docs) == device_family_signatures(docs, eng.params)
+    ).all()
 
 
 def test_fused_resolve_matches_two_stage_hook_path():
@@ -246,7 +260,7 @@ def test_packed_parity_window_and_worker_knobs():
     deep window must never show in the output."""
     rng = np.random.RandomState(13)
     docs = _corpus(rng, 72)
-    want = NearDupEngine(DedupConfig(packed_h2d=False)).dedup_reps(docs)
+    want = NearDupEngine(DedupConfig()).dedup_reps(docs)
     for pw, win in ((1, 1), (3, 1), (4, 6)):
         cfg = DedupConfig(put_workers=pw, dispatch_window=win)
         got = NearDupEngine(cfg).dedup_reps(docs)
@@ -255,7 +269,8 @@ def test_packed_parity_window_and_worker_knobs():
 
 def test_packed_parity_streaming_batch_backend():
     """The stream mode end to end: TpuBatchBackend annotations (exact +
-    near-dup attribution) byte-identical between tile transports."""
+    near-dup attribution) byte-identical across the dispatch knobs (the
+    put pool may reorder tiles; the output must not show it)."""
     from advanced_scrapper_tpu.extractors.tpu_batch import TpuBatchBackend
 
     rng = np.random.RandomState(17)
@@ -273,14 +288,14 @@ def test_packed_parity_streaming_batch_backend():
         out.extend(backend.flush())
         return [(r["url"], r["dup_of"], r["near_dup_of"]) for r in out]
 
-    assert annotate(DedupConfig(packed_h2d=True)) == annotate(
-        DedupConfig(packed_h2d=False)
-    )
+    base = annotate(DedupConfig())
+    assert base == annotate(DedupConfig(put_workers=3, dispatch_window=1))
 
 
 def test_packed_parity_persist_index_mode(tmp_path):
-    """The persist mode: dedup_against_index attributions byte-identical
-    between tile transports (separate index dirs, same corpus stream)."""
+    """The persist mode: dedup_against_index attributions independent of
+    the dispatch knobs (separate index dirs, same corpus stream), and
+    every exact copy across the batches attributed."""
     from advanced_scrapper_tpu.index import PersistentIndex
 
     rng = np.random.RandomState(19)
@@ -297,42 +312,47 @@ def test_packed_parity_persist_index_mode(tmp_path):
             idx.close()
         return out_a.tolist(), out_b.tolist()
 
-    assert run(DedupConfig(packed_h2d=True), "new") == run(
-        DedupConfig(packed_h2d=False), "old"
-    )
+    got = run(DedupConfig(), "base")
+    assert got == run(DedupConfig(put_workers=4, dispatch_window=6), "deep")
+    # the tail of half_b repeats half_a[:8]: each eligible copy finds a
+    # posting (its own first occurrence or an earlier near-dup of it)
+    for k, doc in enumerate(half_a[:8]):
+        if len(doc) >= 5:
+            assert got[1][48 + k] >= 0, (k, got[1][48 + k])
 
 
 def test_signatures_and_keys_matches_host_composition():
-    """The fused (sigs, keys) epilogue — narrow and wide — equals the old
-    host composition (sync sigs, then band_keys*/candidate_keys over
-    them) bit for bit."""
+    """The fused (sigs, keys) epilogue — narrow and wide — equals the
+    host composition (host-twin signatures, then band_keys*/
+    candidate_keys over them) bit for bit."""
     import jax.numpy as jnp
 
+    from advanced_scrapper_tpu.cpu.oracle import device_family_signatures
     from advanced_scrapper_tpu.ops.lsh import band_keys_wide, candidate_keys
 
     rng = np.random.RandomState(23)
     docs = _corpus(rng, 80)
-    new, old = _engines()
-    sigs_old = old.signatures(docs)
-    sigs, keys = new.signatures_and_keys(docs)
-    assert (sigs == sigs_old).all()
+    eng = NearDupEngine(DedupConfig())
+    sigs_ref = device_family_signatures(docs, eng.params)
+    sigs, keys = eng.signatures_and_keys(docs)
+    assert (sigs == sigs_ref).all()
     want = np.asarray(
         candidate_keys(
-            jnp.asarray(sigs_old), old.params.band_salt, old.cfg.cand_subbands
+            jnp.asarray(sigs_ref), eng.params.band_salt, eng.cfg.cand_subbands
         )
     )
     assert (keys == want).all()
-    sigs_w, keys_w = new.signatures_and_keys(docs, wide=True)
-    assert (sigs_w == sigs_old).all()
+    sigs_w, keys_w = eng.signatures_and_keys(docs, wide=True)
+    assert (sigs_w == sigs_ref).all()
     want_w = np.asarray(
         band_keys_wide(
-            jnp.asarray(sigs_old), jnp.asarray(old.params.band_salt)
+            jnp.asarray(sigs_ref), jnp.asarray(eng.params.band_salt)
         )
     )
     assert (keys_w == want_w).all()
     # empty corpus: typed empties, no device work
-    s0, k0 = new.signatures_and_keys([])
-    assert s0.shape == (0, new.params.num_perm) and k0.shape[0] == 0
+    s0, k0 = eng.signatures_and_keys([])
+    assert s0.shape == (0, eng.params.num_perm) and k0.shape[0] == 0
 
 
 def test_pack_roundtrip_unpack():
@@ -540,7 +560,7 @@ def test_oom_backoff_halving_converges_byte_identical(monkeypatch):
         .tobytes()
         for _ in range(512)
     ]
-    eng = NearDupEngine(DedupConfig(packed_h2d=True))
+    eng = NearDupEngine(DedupConfig())
     clean = np.asarray(eng.dedup_reps(docs))
 
     monkeypatch.setenv("ASTPU_CHAOS_DISPATCH_OOM", "2")
@@ -572,7 +592,7 @@ def test_oom_ladder_to_floor_fails_clean(monkeypatch):
 
     rng = np.random.RandomState(12)
     docs = _corpus(rng, 64)
-    eng = NearDupEngine(DedupConfig(packed_h2d=True))
+    eng = NearDupEngine(DedupConfig())
     monkeypatch.setenv("ASTPU_CHAOS_DISPATCH_OOM", "100000")
     dp.reset_chaos_oom()
     with pytest.raises(Exception, match="RESOURCE_EXHAUSTED"):

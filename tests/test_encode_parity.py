@@ -1,15 +1,17 @@
 """Parity certification for the host-path overhaul (PR 2).
 
 The vectorized bucketed encoder (one corpus blob + indexed native cuts,
-bucketed tail batches) and the fused sharded segment-min step are pure
-performance work — every byte of output must match the original paths:
+bucketed tail batches), the kernel backends and the mesh-sharded plane
+are pure performance work — every byte of output must match a reference:
 
 - signatures / dedup_reps through the native encoder vs the pure-Python
   ``core.tokenizer.encode_blocks`` loop (the behavioural oracle);
 - ``encode_blocks_ranges`` vs ``encode_blocks`` on each width group;
 - ``bucket_widths`` vs the scalar ``bucket_len``;
-- ``make_sharded_block_dedup`` (device-fused per-article combine) vs the
-  certified engine's representatives.
+- the scan and pallas backends vs the host twin of the device hash
+  family (``cpu.oracle.device_family_signatures``) through banding;
+- ``dedup_reps_sharded`` (the mesh-sharded packed plane) vs the
+  single-device async engine's representatives.
 """
 
 from __future__ import annotations
@@ -99,11 +101,13 @@ def test_signatures_native_vs_python_paths(monkeypatch):
 
 
 def test_single_dispatch_backend_parity_through_banding():
-    """scan vs pallas vs the packed single-dispatch path, bit-identical
-    THROUGH BANDING: signatures, coarse+fine candidate keys (the fused
-    epilogue) and resolved representatives must agree across all three
-    routes — the ISSUE 9 backend-parity gate for the fused tile step."""
+    """scan vs pallas through the packed single-dispatch path,
+    bit-identical THROUGH BANDING: signatures equal the host twin of the
+    device hash family, and coarse+fine candidate keys (the fused
+    epilogue) and resolved representatives agree across both backends —
+    the backend-parity gate for the fused tile step."""
     from advanced_scrapper_tpu.config import DedupConfig
+    from advanced_scrapper_tpu.cpu.oracle import device_family_signatures
     from advanced_scrapper_tpu.pipeline.dedup import NearDupEngine
 
     rng = np.random.RandomState(7)
@@ -119,76 +123,20 @@ def test_single_dispatch_backend_parity_through_banding():
             )
     shape = dict(block_len=1024, batch_size=64)
     routes = {
-        "scan-packed": DedupConfig(backend="scan", packed_h2d=True, **shape),
-        "scan-legacy": DedupConfig(backend="scan", packed_h2d=False, **shape),
-        "pallas-packed": DedupConfig(
-            backend="pallas", packed_h2d=True, **shape
-        ),
-        "pallas-legacy": DedupConfig(
-            backend="pallas", packed_h2d=False, **shape
-        ),
+        "scan": DedupConfig(backend="scan", **shape),
+        "pallas": DedupConfig(backend="pallas", **shape),
     }
     outs = {}
     for name, cfg in routes.items():
         eng = NearDupEngine(cfg)
         sigs, keys = eng.signatures_and_keys(docs)
         outs[name] = (sigs, keys, eng.dedup_reps(docs))
-    ref_sigs, ref_keys, ref_reps = outs["scan-packed"]
+    want_sigs = device_family_signatures(docs, eng.params)
+    _, ref_keys, ref_reps = outs["scan"]
     for name, (sigs, keys, reps) in outs.items():
-        assert (sigs == ref_sigs).all(), name
+        assert (sigs == want_sigs).all(), name
         assert (keys == ref_keys).all(), name
         assert (reps == ref_reps).all(), name
-
-
-def test_fused_sharded_block_dedup_matches_engine():
-    """The device-fused per-article segment-min (make_sharded_block_dedup)
-    must resolve blockwise corpora exactly like the certified engine's
-    async path (same candidate bands, same fine thresholds)."""
-    import jax
-
-    from advanced_scrapper_tpu.config import DedupConfig
-    from advanced_scrapper_tpu.core.hashing import make_params
-    from advanced_scrapper_tpu.core.mesh import build_mesh
-    from advanced_scrapper_tpu.parallel.sharded import make_sharded_block_dedup
-    from advanced_scrapper_tpu.pipeline.dedup import NearDupEngine, _jump_rounds
-
-    rng = np.random.RandomState(5)
-    texts: list[bytes] = []
-    for i in range(96):
-        if i >= 4 and rng.rand() < 0.3:
-            texts.append(texts[rng.randint(0, i)])
-        else:
-            texts.append(
-                rng.randint(32, 127, size=rng.randint(20, 9000),
-                            dtype=np.uint8).tobytes()
-            )
-    cfg = DedupConfig()
-    params = make_params()
-    want = np.asarray(NearDupEngine(cfg, params).dedup_reps_async(texts))[
-        : len(texts)
-    ]
-
-    tok, lens, owners = encode_blocks(texts, 2048, overlap=params.shingle_k - 1)
-    mesh = build_mesh(len(jax.devices()), 1)
-    ndev = len(jax.devices())
-    owners = owners.astype(np.int32)
-    if tok.shape[0] % ndev:  # pad blocks to shard divisibility: scratch rows
-        pad = ndev - tok.shape[0] % ndev
-        tok = np.concatenate([tok, np.zeros((pad, tok.shape[1]), np.uint8)])
-        lens = np.concatenate([lens, np.zeros((pad,), np.int32)])
-        owners = np.concatenate(
-            [owners, np.full((pad,), len(texts), np.int32)]
-        )
-    step = make_sharded_block_dedup(
-        mesh, params, len(texts),
-        threshold=cfg.sim_threshold,
-        jump_rounds=_jump_rounds(bucket_len(len(texts), min_bucket=64)),
-        cand_subbands=cfg.cand_subbands,
-        fine_margin=cfg.fine_margin,
-    )
-    rep, hist = step(tok, lens, owners)
-    assert (np.asarray(rep) == want).all()
-    assert int(np.asarray(hist).sum()) > 0
 
 
 def test_dedup_reps_sharded_matches_async_engine():

@@ -4,11 +4,10 @@ always-on device-traffic counters that gate the launch-count win
 numerically — mirroring ``test_dispatch.py``'s certification of the dedup
 half of the ledger.
 
-Certification strategy: the packed transport is pure performance work, so
-matcher OUTPUT must be byte-identical to the legacy per-batch screen loop
-(``ASTPU_MATCH_PACKED=0`` / ``packed=False``) across screen-only, forced
-refine, overlong-fallback and pooled/inline verify modes — and both must
-equal the unscreened reference scan (the standing golden).
+Certification strategy: the screen is pure performance work, so matcher
+OUTPUT must equal the unscreened exact host scan (``use_screen=False``,
+the standing golden) across screen-only, forced refine, overlong-fallback
+and pooled/inline verify modes.
 """
 
 from __future__ import annotations
@@ -94,20 +93,20 @@ def _norm(res):
 
 
 def test_per_tile_traffic_one_put_one_dispatch_vs_legacy():
-    """Packed path: exactly 1 put + 1 dispatch per screen tile, nothing
-    else per chunk; instrumented legacy loop: 4 array puts + 1 screen
-    dispatch per batch — asserted via the ALWAYS-ON counters, so the
-    drop is a measured number, not prose."""
+    """Exactly 1 put + 1 dispatch per screen tile, nothing else per
+    chunk — asserted via the ALWAYS-ON counters, so the contract is a
+    measured number, not prose; the unscreened host scan moves no device
+    traffic at all."""
     idx = _index()
     df = _chunk(96, pad_every=7)
 
     probe: list[dict] = []
     idx.dispatch_probe = probe.append
     d0 = stages.device_counters()
-    packed = match_chunk(df, idx, packed=True)
+    packed = match_chunk(df, idx)
     d1 = stages.device_counters()
     idx.dispatch_probe = None
-    legacy = match_chunk(df, idx, packed=False, screen_batch=32)
+    unscreened = match_chunk(df, idx, use_screen=False)
     d2 = stages.device_counters()
 
     tiles = len(probe)
@@ -120,14 +119,10 @@ def test_per_tile_traffic_one_put_one_dispatch_vs_legacy():
     assert puts_p == tiles, (puts_p, tiles)
     assert disp_p == tiles, (disp_p, tiles)
     assert bytes_p == sum(t["h2d_bytes"] for t in probe)
-    # legacy (screen-only): 4 puts + 1 dispatch per fixed batch
-    n_batches = -(-len(df) // 32)
-    puts_l = d2["device_puts"] - d1["device_puts"]
-    disp_l = d2["device_dispatches"] - d1["device_dispatches"]
-    assert puts_l == 4 * n_batches, (puts_l, n_batches)
-    assert disp_l == n_batches
+    assert d2["device_puts"] == d1["device_puts"]
+    assert d2["device_dispatches"] == d1["device_dispatches"]
     # and the outputs are byte-identical
-    assert _norm(packed) == _norm(legacy)
+    assert _norm(packed) == _norm(unscreened)
     assert len(packed) >= len(df) // 8
 
 
@@ -135,7 +130,7 @@ def test_probe_reports_tile_geometry():
     idx = _index()
     probe: list[dict] = []
     idx.dispatch_probe = probe.append
-    match_chunk(_chunk(40), idx, packed=True)
+    match_chunk(_chunk(40), idx)
     idx.dispatch_probe = None
     assert probe
     for t in probe:
@@ -151,21 +146,21 @@ def test_packed_parity_screen_only_and_unscreened():
     idx = _index()
     df = _chunk(64, pad_every=9)
     want = _norm(match_chunk(df, idx, use_screen=False))
-    assert _norm(match_chunk(df, idx, packed=True)) == want
-    assert _norm(match_chunk(df, idx, packed=False)) == want
+    assert _norm(match_chunk(df, idx)) == want
+    assert _norm(match_chunk(df, idx, use_refine=False)) == want
     assert len(want) >= 8
 
 
 def test_packed_parity_forced_refine():
-    """Forced refine: the fused screen+bound step (packed) and the
-    screen-then-bound legacy dispatches must produce identical matches —
-    both prune sets are sound, so neither may change a decision."""
+    """Forced refine: the fused screen+bound step must produce the
+    unscreened scan's matches — its prune set is sound, so it may not
+    change a decision."""
     idx = _index()
     df = _chunk(48, seed=7)
     want = _norm(match_chunk(df, idx, use_screen=False))
-    got_p = _norm(match_chunk(df, idx, use_refine=True, packed=True))
-    got_l = _norm(match_chunk(df, idx, use_refine=True, packed=False))
-    assert got_p == got_l == want
+    got_p = _norm(match_chunk(df, idx, use_refine=True))
+    got_s = _norm(match_chunk(df, idx, use_refine=False))
+    assert got_p == got_s == want
 
 
 def test_packed_parity_pooled_verify():
@@ -175,11 +170,11 @@ def test_packed_parity_pooled_verify():
     if pool is None:
         pytest.skip("host refuses worker processes")
     try:
-        got_p = _norm(match_chunk(df, idx, packed=True, pool=pool))
-        got_l = _norm(match_chunk(df, idx, packed=False, pool=pool))
+        got_p = _norm(match_chunk(df, idx, pool=pool))
+        got_u = _norm(match_chunk(df, idx, use_screen=False, pool=pool))
     finally:
         pool.shutdown()
-    assert got_p == got_l == _norm(match_chunk(df, idx, use_screen=False))
+    assert got_p == got_u == _norm(match_chunk(df, idx, use_screen=False))
 
 
 def test_packed_parity_window_and_put_worker_knobs():
@@ -188,37 +183,12 @@ def test_packed_parity_window_and_put_worker_knobs():
     window must never show in the output."""
     idx = _index()
     df = _chunk(72, seed=3, pad_every=5)
-    want = _norm(match_chunk(df, idx, packed=False))
+    want = _norm(match_chunk(df, idx, use_screen=False))
     for pw, win in ((1, 1), (3, 1), (4, 6)):
         got = match_chunk(
-            df, idx, packed=True, screen_put_workers=pw, dispatch_window=win
+            df, idx, screen_put_workers=pw, dispatch_window=win
         )
         assert _norm(got) == want, (pw, win)
-
-
-def test_env_knob_selects_transport(monkeypatch):
-    """ASTPU_MATCH_PACKED=0 keeps the legacy loop runnable with no code
-    change (the acceptance escape hatch); the env default is packed."""
-    idx = _index()
-    df = _chunk(32)
-    want = _norm(match_chunk(df, idx, packed=False))
-
-    monkeypatch.setenv("ASTPU_MATCH_PACKED", "0")
-    d0 = stages.device_counters()
-    got = match_chunk(df, idx)  # env-resolved: legacy → 4 puts/batch
-    d1 = stages.device_counters()
-    assert _norm(got) == want
-    assert d1["device_puts"] - d0["device_puts"] == 4  # one 128-row batch
-
-    monkeypatch.setenv("ASTPU_MATCH_PACKED", "1")
-    probe: list[dict] = []
-    idx.dispatch_probe = probe.append
-    d1 = stages.device_counters()
-    got = match_chunk(df, idx)
-    d2 = stages.device_counters()
-    idx.dispatch_probe = None
-    assert _norm(got) == want
-    assert d2["device_puts"] - d1["device_puts"] == len(probe) > 0
 
 
 # -- overlong-article fallback (previously untested) --------------------------
@@ -261,8 +231,8 @@ def _overlong_frame() -> pd.DataFrame:
 @pytest.mark.parametrize("use_refine", [False, True])
 def test_overlong_fallback_parity_both_transports(use_refine):
     """Rows above ``screen_block`` must fall back to the full host scan —
-    decisions identical to the unscreened reference — on BOTH transports,
-    and (packed) must never ship an overlong row's bytes to the device."""
+    decisions identical to the unscreened reference — in both screen
+    modes, and must never ship an overlong row's bytes to the device."""
     idx = _index()
     df = _overlong_frame()
     block = 4096
@@ -272,13 +242,10 @@ def test_overlong_fallback_parity_both_transports(use_refine):
     probe: list[dict] = []
     idx.dispatch_probe = probe.append
     got_p = match_chunk(
-        df, idx, packed=True, screen_block=block, use_refine=use_refine
+        df, idx, screen_block=block, use_refine=use_refine
     )
     idx.dispatch_probe = None
-    got_l = match_chunk(
-        df, idx, packed=False, screen_block=block, use_refine=use_refine
-    )
-    assert _norm(got_p) == _norm(got_l) == want
+    assert _norm(got_p) == want
     # only the one short row entered a tile: 16 bucketed rows, 1024 wide
     assert sum(t["rows"] for t in probe) == 16
     assert all(t["width"] == 1024 for t in probe)
@@ -295,11 +262,14 @@ def test_overlong_counter_counts_on_both_transports():
         )
 
     base = overlong_total()
-    match_chunk(df, idx, packed=True, screen_block=4096)
-    after_packed = overlong_total()
-    assert after_packed - base == 2  # the two >4096 rows
-    match_chunk(df, idx, packed=False, screen_block=4096)
-    assert overlong_total() - after_packed == 2
+    match_chunk(df, idx, screen_block=4096)
+    after_screen = overlong_total()
+    assert after_screen - base == 2  # the two >4096 rows
+    match_chunk(df, idx, screen_block=4096, use_refine=True)
+    assert overlong_total() - after_screen == 2
+    # the unscreened scan routes nothing: it never counts an overlong row
+    match_chunk(df, idx, use_screen=False, screen_block=4096)
+    assert overlong_total() - after_screen == 2
 
 
 # -- the fused kernel's parts -------------------------------------------------
@@ -401,7 +371,6 @@ def test_many_tiles_bounded_readback_parity():
     got = match_chunk(
         df,
         idx,
-        packed=True,
         screen_tile_bytes=1 << 14,  # 16-row tiles at width 1024
         dispatch_window=1,
         screen_put_workers=1,
@@ -440,7 +409,7 @@ def test_prewarm_compiles_the_chunker_shape_set():
     sizes = step._cache_size()
     df = _chunk(40, pad_every=6)
     out = match_chunk(
-        df, idx, packed=True, screen_block=block, screen_tile_bytes=tile_bytes
+        df, idx, screen_block=block, screen_tile_bytes=tile_bytes
     )
     assert step._cache_size() == sizes, "chunk compiled outside the prewarmed set"
     assert _norm(out) == _norm(match_chunk(df, idx, use_screen=False))

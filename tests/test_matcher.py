@@ -157,7 +157,7 @@ def test_screened_equals_unscreened_golden():
         )
     df = pd.DataFrame(rows)
     idx = _index()
-    screened = match_chunk(df, idx, use_screen=True, screen_batch=16)
+    screened = match_chunk(df, idx, use_screen=True, screen_tile_bytes=1 << 14)
     unscreened = match_chunk(df, idx, use_screen=False)
 
     def norm(res):
@@ -289,17 +289,33 @@ def test_screen_sound_for_nondefault_threshold():
 
 
 def test_screen_exact_path_prunes_impossible_substrings():
-    """ALL-CAPS names longer than both parts can never match → pruned."""
-    from advanced_scrapper_tpu.ops.match import match_screen, prepare_names
+    """ALL-CAPS names longer than both parts can never match → pruned,
+    through the packed single-dispatch screen step."""
+    import jax.numpy as jnp
+
+    from advanced_scrapper_tpu.ops.match import (
+        MASK_SCREEN_KEEP,
+        make_screen_step,
+        prepare_names,
+    )
+    from advanced_scrapper_tpu.ops.pack import pack_tile_planes
     from advanced_scrapper_tpu.core.tokenizer import encode_batch
     import numpy as np
 
-    tables = prepare_names([b"VERYLONGTICKERNAME"], fuzzy=np.array([False]))
+    tables = prepare_names(
+        [b"VERYLONGTICKERNAME", b"body"], fuzzy=np.array([False, False])
+    )
     doc = b"short\nbody"
     tok, ln = encode_batch([doc], block_len=64)
-    keep = match_screen(tok, np.array([4], np.int32), np.array([5], np.int32),
-                        ln, tables)
+    one = lambda v: np.array([v], np.int32)  # noqa: E731
+    packed = pack_tile_planes(tok, ln, one(4), one(5), one(0), one(0))
+    step = make_screen_step(tables)
+    mask, owners = step(jnp.asarray(packed), 95.0, rows=1, width=64)
+    keep = np.asarray(mask) & MASK_SCREEN_KEEP
     assert not keep[0, 0]
+    # a name that fits a part and whose grams are all present survives
+    assert keep[0, 1]
+    assert int(np.asarray(owners)[0]) == 0
 
 
 def test_verify_pool_output_identical_to_serial():
@@ -427,10 +443,9 @@ def test_refine_auto_mode_semantics(monkeypatch):
     ]
     df = pd.DataFrame(rows)
 
-    # the bound's engagement is counted mode-neutrally: on the packed
-    # plane refine IS the fused screen+bound step (no separate kernel
-    # call exists), on the legacy loop it is the prune_mask_tables
-    # dispatch — both land in the same counter
+    # the bound's engagement is counted on the screen-step mode: refine
+    # IS the fused screen+bound step (no separate kernel call exists);
+    # a direct prune_mask_tables call would land in the same counter
     calls = {"n": 0}
     real = ED.prune_mask_tables
 

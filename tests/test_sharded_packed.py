@@ -1,8 +1,9 @@
 """Pod-scale packed dedup (ISSUE 13): the fused donated tile step sharded
 over a device mesh — per-shard donation, per-shard launch ledger, byte
-parity against BOTH oracles (the single-device fused plane and the legacy
-unpacked sharded path), the shared-prewarm jit-cache contract, and the
-sharded band-key fan-out into the persistent-index plane.
+parity against BOTH oracles (the single-device fused plane and the plain
+numpy estimator-only LSH resolution, ``cpu.oracle.oracle_lsh_reps``), the
+shared-prewarm jit-cache contract, and the sharded band-key fan-out into
+the persistent-index plane.
 
 Certification strategy mirrors PR 9: the packed sharded transport is pure
 performance work, so every representative (and every index attribution)
@@ -50,23 +51,58 @@ def mesh81(devices8):
 # -- byte parity against both oracles -----------------------------------------
 
 
+def _plain_reps(eng: NearDupEngine, docs: list[bytes]) -> np.ndarray:
+    """The plain numpy reference: the host twin of the device hash family
+    resolved by the estimator-only LSH reference."""
+    from advanced_scrapper_tpu.cpu.oracle import (
+        device_family_signatures,
+        oracle_lsh_reps,
+    )
+
+    valid = np.fromiter(map(len, docs), np.int64) >= eng.params.shingle_k
+    return oracle_lsh_reps(
+        device_family_signatures(docs, eng.params),
+        eng.params,
+        cand_subbands=eng.cfg.cand_subbands,
+        threshold=eng.cfg.sim_threshold,
+        valid=valid,
+    )
+
+
+#: the estimator-only configuration the plain reference models
+ESTIMATOR_ONLY = DedupConfig(rerank=False, exact_verify_band=0.0)
+
+
 def test_sharded_packed_matches_both_oracles(mesh42, mesh81):
     """The acceptance triangle: packed-sharded representatives must equal
-    the single-device fused oracle AND the legacy unpacked sharded path,
-    on a 4x2 and an 8x1 mesh (a shard is a device, whatever the dp/sp
+    the single-device fused path AND the plain numpy LSH resolution, on
+    a 4x2 and an 8x1 mesh (a shard is a device, whatever the dp/sp
     factorisation)."""
     rng = np.random.RandomState(3)
     docs = _corpus(rng, 128)
-    eng = NearDupEngine(DedupConfig(packed_h2d=True))
+    eng = NearDupEngine(ESTIMATOR_ONLY)
     want = np.asarray(eng.dedup_reps_async(docs))[: len(docs)]
+    assert (want == _plain_reps(eng, docs)).all()
     for mesh in (mesh42, mesh81):
         got = eng.dedup_reps_sharded(docs, mesh)
         assert (got == want).all(), mesh.shape
-    # the legacy oracle compiles a whole resolution program per mesh —
-    # one mesh suffices (the MULTICHIP dryrun re-certifies per count)
-    legacy = NearDupEngine(DedupConfig(packed_h2d=False))
-    got_legacy = legacy.dedup_reps_sharded(docs, mesh42)
-    assert (want == got_legacy).all()
+
+
+@pytest.mark.parametrize(
+    "seed,n_docs,batch_size", [(21, 96, 64), (22, 160, 1024)]
+)
+def test_sharded_packed_matches_plain_reference(mesh42, seed, n_docs, batch_size):
+    """More seeds and tile shapes of the sharded plane against the plain
+    numpy LSH resolution: 64-row tiles spread one corpus over many tile
+    groups (and an article's blocks over several shards); 1024-row tiles
+    leave most shards a zero padding tile."""
+    rng = np.random.RandomState(seed)
+    docs = _corpus(rng, n_docs)
+    eng = NearDupEngine(
+        DedupConfig(rerank=False, exact_verify_band=0.0, batch_size=batch_size)
+    )
+    got = eng.dedup_reps_sharded(docs, mesh42)
+    assert (got == _plain_reps(eng, docs)).all()
 
 
 def test_sharded_packed_parity_fine_margin_and_oph(mesh42):
@@ -100,25 +136,18 @@ def test_sharded_packed_window_and_worker_knobs(mesh81):
 
 def test_sharded_packed_empty_and_env_routing(mesh81, monkeypatch):
     """Empty corpus returns a typed empty with no device work; the
-    ASTPU_DEDUP_PACKED_H2D=0 escape hatch routes the same entry point to
-    the legacy transport (the parity oracle stays one env var away)."""
+    removed ASTPU_DEDUP_PACKED_H2D=0 switch fails loudly instead of
+    silently selecting a transport that no longer exists."""
     from advanced_scrapper_tpu.config import from_env
 
     eng = NearDupEngine(DedupConfig())
     out = eng.dedup_reps_sharded([], mesh81)
     assert out.shape == (0,) and out.dtype == np.int32
     monkeypatch.setenv("ASTPU_DEDUP_PACKED_H2D", "0")
-    cfg = from_env(DedupConfig, "dedup")
-    assert cfg.packed_h2d is False
-    rng = np.random.RandomState(5)
-    docs = _corpus(rng, 48)
-    legacy_eng = NearDupEngine(cfg)
-    got = legacy_eng.dedup_reps_sharded(docs, mesh81)
-    # the legacy route leaves the shard-labelled ledger untouched
-    want = np.asarray(
-        NearDupEngine(DedupConfig()).dedup_reps_async(docs)
-    )[: len(docs)]
-    assert (got == want).all()
+    with pytest.raises(ValueError, match="MIGRATION.md"):
+        from_env(DedupConfig, "dedup")
+    monkeypatch.setenv("ASTPU_DEDUP_PACKED_H2D", "1")
+    assert from_env(DedupConfig, "dedup").packed_h2d is True
 
 
 # -- per-shard launch ledger (the acceptance gate) -----------------------------
@@ -168,17 +197,12 @@ def test_sharded_fused_step_donates_per_shard(mesh42):
     """The sharded running accumulator is DONATED into the partitioned
     step — pjit rebases the donation per shard, so after a call the old
     global buffer (and every per-shard slice of it) is dead, and the fold
-    is bit-exact vs the single-device accumulate on each shard's tile."""
+    is bit-exact vs a plain per-owner numpy fold of each shard's tile."""
     import jax
-    import jax.numpy as jnp
 
     from advanced_scrapper_tpu.core.hashing import make_params
-    from advanced_scrapper_tpu.ops.minhash import (
-        accumulate_block_signatures,
-        minhash_signatures,
-    )
+    from advanced_scrapper_tpu.cpu.oracle import device_family_signatures
     from advanced_scrapper_tpu.ops.pack import pack_tile
-    from advanced_scrapper_tpu.ops.shingle import U32_MAX
     from advanced_scrapper_tpu.parallel.sharded_packed import (
         assemble_packed_tiles,
         local_shard_rows,
@@ -217,16 +241,13 @@ def test_sharded_fused_step_donates_per_shard(mesh42):
     with pytest.raises(RuntimeError):
         np.asarray(running)  # the donated buffer is unusable afterwards
     # per-shard fold parity: shard s's accumulator row equals the
-    # single-device accumulate of shard s's tile alone
+    # per-owner np.minimum.at fold of shard s's tile alone
     got = np.asarray(out)
     for s, (tok, lens, owners) in enumerate(tiles):
-        want = accumulate_block_signatures(
-            jnp.full((n_bucket, params.num_perm), U32_MAX, jnp.uint32),
-            minhash_signatures(jnp.asarray(tok), jnp.asarray(lens), params),
-            jnp.asarray(owners),
-            num_articles=n_bucket,
-        )
-        assert (got[s] == np.asarray(want)).all(), s
+        want = np.full((n_bucket, params.num_perm), 0xFFFFFFFF, np.uint32)
+        rows_s = [bytes(tok[i, : lens[i]]) for i in range(rows)]
+        np.minimum.at(want, owners, device_family_signatures(rows_s, params))
+        assert (got[s] == want).all(), s
     # and the epilogue's pmin-combine equals the elementwise min of rows
     epi = make_sharded_resolve_epilogue(
         mesh42, params,

@@ -162,7 +162,7 @@ def test_screen_step_compiles(one_chip, entity_index, refine):
     rows = _screen_tile_rows(_match_cfg().screen_tile_bytes, width)
     bound = None
     if refine:  # the same tables pipeline.matcher._screen_steps builds
-        cols, _names, (masks, lens, ok) = _refine_candidates(entity_index)
+        cols, (masks, lens, ok) = _refine_candidates(entity_index)
         assert len(cols)
         bound = (masks, lens, ok, cols)
     step = make_screen_step(entity_index.screen_tables(), bound)
